@@ -46,7 +46,8 @@ def main():
                     shape=args.shape)
     G = "x".join(str(g) for g in r.grid)
     print(f"minimod[{r.mode}]: grid {G}, {r.steps} steps on "
-          f"{r.nz}x{r.ny} ranks -> {r.wall_s * 1e3:.0f} ms (incl. compile)")
+          f"{r.nz}x{r.ny} ranks -> {r.wall_s * 1e3:.0f} ms "
+          f"(+{r.compile_s * 1e3:.0f} ms compile)")
     print(f"  decomposition: z_extents={r.z_extents} "
           f"(PGAS region bytes/rank: {r.region_sizes})")
     print(f"  halo plan: overlap={r.plan.overlap} slots={r.plan.slots} "

@@ -149,7 +149,11 @@ class MinimodResult:
     """One Minimod run plus its audit trail."""
 
     field: np.ndarray                  # (Z, Y, X) logical wavefield
-    wall_s: float
+    wall_s: float                      # the time loop, compile excluded
+    compile_s: float
+    # Pallas kernels (tpu_custom_call ops) in the compiled program: 0 means
+    # the XLA path ran (always so off the TPU)
+    kernel_calls: int
     mode: str
     grid: Tuple[int, int, int]
     steps: int
@@ -316,8 +320,10 @@ def run_minimod(
                               in_specs=(P("z", "y"), P("z", "y")),
                               out_specs=P("z", "y")))
         t0 = time.perf_counter()
-        out = jax.block_until_ready(f(u_in, up_in))
-        wall = time.perf_counter() - t0
+        compiled = f.lower(u_in, up_in).compile()
+        t1 = time.perf_counter()
+        out = jax.block_until_ready(compiled(u_in, up_in))
+        wall = time.perf_counter() - t1
 
         for h in handles:
             ctx.memory.free(h)
@@ -325,7 +331,9 @@ def run_minimod(
         bstats = ctx.byte_stats()
         result = MinimodResult(
             field=unpad_shards(fetch_global(out), z_extents),
-            wall_s=wall, mode=mode, grid=grid, steps=steps, nz=nz, ny=ny,
+            wall_s=wall, compile_s=t1 - t0,
+            kernel_calls=compiled.as_text().count("tpu_custom_call"),
+            mode=mode, grid=grid, steps=steps, nz=nz, ny=ny,
             z_extents=z_extents, plan=used_plan,
             puts=sum(ops.get("put", 0) for ops in stats.values()),
             put_bytes=sum(ops.get("put", 0) for ops in bstats.values()),

@@ -1,4 +1,4 @@
-"""stablelm-3b [dense] — [hf:stabilityai/stablelm-2-1_6b; unverified].
+"""stablelm-3b [dense] — [hf:stabilityai/stablelm-3b-4e1t; hf].
 
 32L d_model=2560 32H (MHA kv=32) d_ff=6912 vocab=50304.  StableLM uses
 partial rotary (25%).

@@ -85,7 +85,6 @@ def ensure_varying(x, axes: Tuple[str, ...]):
     A collective over a group must see its operand varying on every group
     axis; values that are invariant on some axis (e.g. a loss already
     psum'd over "model") are pvary'd first — a pure type-level operation.
-    On pre-vma jax this is the identity.
     """
     def promote(v):
         vma = getattr(typeof(v), "vma", frozenset())

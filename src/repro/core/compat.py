@@ -1,34 +1,23 @@
-"""JAX version compatibility shims.
+"""Thin names over the installed JAX (0.9) used across the codebase.
 
-The runtime targets the modern explicit-vma API surface (``jax.shard_map``,
-``jax.typeof``, ``lax.pcast``, the invariant all-gather); the jax pinned in
-this container (0.4.37) still keeps ``shard_map`` under ``jax.experimental``
-and predates vma tracking entirely.  Every site in src/tests/examples/
-benchmarks imports these names from here so the rest of the codebase is
-version-agnostic:
+Every site in src/tests/examples/benchmarks imports these from here:
 
-* :func:`shard_map` — ``jax.shard_map`` when present, else the experimental
-  one with ``check_rep=False`` (vma/replication discipline is enforced by
-  our own ``ensure_varying`` calls, which the old checker cannot see);
-* :func:`typeof` — ``jax.typeof`` or the abstract-value fallback.  Callers
-  read ``.vma`` via ``getattr(..., "vma", frozenset())`` so the fallback's
-  lack of vma degrades to "promote everything", which :func:`pcast` then
-  turns into a no-op;
-* :func:`pcast` — ``lax.pcast`` or identity (pre-vma jax has no
-  varying/invariant distinction, so the promotion is vacuous);
-* :func:`all_gather_invariant` — falls back to ``lax.all_gather`` (same
-  wire bytes; only the type-level replication annotation is lost);
-* :func:`make_mesh` — swallows ``axis_types`` on jax builds whose
-  ``jax.make_mesh`` does not accept it yet.
+* :func:`shard_map`, :func:`typeof`, :func:`pcast`, :func:`axis_size` —
+  ``jax.shard_map`` (vma checking on), ``jax.typeof``, ``lax.pcast`` and
+  ``lax.axis_size`` themselves;
+* :func:`all_gather_invariant` — the Varying->Invariant all-gather, which
+  JAX keeps internal;
+* :func:`make_mesh` — ``jax.make_mesh`` with every axis ``Auto`` unless
+  told otherwise.
 """
 
 from __future__ import annotations
 
-import inspect
-from typing import Any, Optional, Sequence
+from typing import Optional, Sequence
 
 import jax
 from jax import lax
+from jax._src.lax.parallel import all_gather_invariant
 
 __all__ = [
     "shard_map",
@@ -37,115 +26,22 @@ __all__ = [
     "axis_size",
     "all_gather_invariant",
     "make_mesh",
-    "HAS_VMA",
 ]
 
-
-# -- vma (varying-manual-axes) typing ----------------------------------------
-
-HAS_VMA = hasattr(jax, "typeof") and hasattr(lax, "pcast")
-
-
-# -- shard_map ---------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_PARAMS = frozenset(
-        inspect.signature(_shard_map).parameters)
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_PARAMS = frozenset(
-        inspect.signature(_shard_map).parameters)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """Version-stable ``shard_map``.
-
-    On pre-vma jax the old ``check_rep`` checker cannot see our explicit
-    ``ensure_varying`` promotions and would reject programs the vma type
-    system accepts, so it is disabled there.  On vma-capable jax the
-    default checking stays ON — the implicit pvary-transpose psums that
-    train/step.py's HAS_VMA branch relies on require it.
-    """
-    if not HAS_VMA and "check_rep" in _SHARD_MAP_PARAMS:
-        kwargs.setdefault("check_rep", False)
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-
-
-if hasattr(jax, "typeof"):
-    typeof = jax.typeof
-else:
-    def typeof(x) -> Any:
-        """Abstract value of ``x``; has no ``.vma`` attribute on old jax."""
-        return jax.core.get_aval(x)
-
-
-if hasattr(lax, "pcast"):
-    pcast = lax.pcast
-else:
-    def pcast(x, axes, *, to: str = "varying"):
-        """Identity: pre-vma jax has no varying/invariant distinction."""
-        del axes, to
-        return x
-
-
-# -- named-axis size ---------------------------------------------------------
-
-if hasattr(lax, "axis_size"):
-    axis_size = lax.axis_size
-else:
-    def axis_size(axis_name) -> int:
-        """Static size of a named mesh axis under trace.
-
-        ``psum`` of the literal 1 constant-folds to the axis size as a
-        Python int on every jax version — the documented pre-``axis_size``
-        idiom.
-        """
-        return lax.psum(1, axis_name)
-
-
-# -- invariant all-gather ----------------------------------------------------
-
-try:  # pragma: no cover - depends on the installed jax
-    from jax._src.lax.parallel import all_gather_invariant as \
-        _all_gather_invariant
-except ImportError:
-    _all_gather_invariant = None
-
-
-def all_gather_invariant(x, axis_name, *, axis: int = 0, tiled: bool = False):
-    """Varying->Invariant all-gather, or the plain one where unsupported.
-
-    Numerically identical either way; the invariant form only adds the
-    type-level fact that every rank holds the same bytes afterwards.
-    """
-    if _all_gather_invariant is not None:
-        return _all_gather_invariant(x, axis_name, axis=axis, tiled=tiled)
-    return lax.all_gather(x, axis_name, axis=axis, tiled=tiled)
-
-
-# -- mesh construction -------------------------------------------------------
-
-_MAKE_MESH_PARAMS = frozenset(inspect.signature(jax.make_mesh).parameters)
+shard_map = jax.shard_map
+typeof = jax.typeof
+pcast = lax.pcast
+axis_size = lax.axis_size
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               *, axis_types: Optional[Sequence] = None, devices=None):
-    """``jax.make_mesh`` that tolerates the ``axis_types`` kwarg everywhere.
+    """``jax.make_mesh`` whose ``axis_types`` default to all ``Auto``.
 
-    ``axis_types`` may be a tuple of ``jax.sharding.AxisType`` (new jax), the
-    string ``"auto"`` (resolved here), or None.  Old jax has neither the
-    kwarg nor the enum; all axes are implicitly Auto there, so dropping the
-    argument preserves behavior.
+    ``axis_types`` may be a tuple of ``jax.sharding.AxisType``, the string
+    ``"auto"``, or None (also all ``Auto``).
     """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    if "axis_types" in _MAKE_MESH_PARAMS and \
-            hasattr(jax.sharding, "AxisType"):
-        if axis_types is None or axis_types == "auto":
-            axis_types = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
-        kwargs["axis_types"] = tuple(axis_types)
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    if axis_types is None or axis_types == "auto":
+        axis_types = (jax.sharding.AxisType.Auto,) * len(tuple(axis_names))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=tuple(axis_types), devices=devices)

@@ -10,9 +10,10 @@ shard_map.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
-__all__ = ["zeros_varying", "full_varying"]
+__all__ = ["zeros_varying", "full_varying", "out_struct"]
 
 
 def zeros_varying(shape, dtype, like):
@@ -24,3 +25,11 @@ def zeros_varying(shape, dtype, like):
 def full_varying(shape, dtype, value, like):
     tag = (like.reshape(-1)[0] * 0).astype(dtype)
     return jnp.full(shape, value, dtype) + tag
+
+
+def out_struct(shape, dtype, *operands) -> jax.ShapeDtypeStruct:
+    """A kernel's output shape, varying over every axis its operands vary
+    over: ``pallas_call`` under a vma-checked ``shard_map`` needs the
+    output's vma spelled out (outside shard_map it is empty)."""
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma)

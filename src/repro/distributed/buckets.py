@@ -313,6 +313,7 @@ def pack_buckets(grads: Mapping[str, jax.Array], plan: BucketPlan,
     own sharded axes differ), and a concat operand set must agree.
     """
     from repro.core.backends import ensure_varying
+    from repro.core.compat import typeof
 
     out = {}
     for b in plan.buckets:
@@ -321,12 +322,19 @@ def pack_buckets(grads: Mapping[str, jax.Array], plan: BucketPlan,
             flat = grads[s.name].astype(F32).reshape(-1)
             if not (s.start == 0 and s.size == flat.size):
                 flat = flat[s.start:s.start + s.size]
-            if vary:
-                flat = ensure_varying(flat, vary)
             pieces.append(flat)
+        if vary:
+            # the bucket's own axes plus whatever its members already vary
+            # over: promoting further would leave the reduced grads varying
+            # over axes their parameters are replicated on
+            axes = set(b.axes)
+            for flat in pieces:
+                axes |= set(getattr(typeof(flat), "vma", ()))
+            axes = tuple(a for a in vary if a in axes)
+            pieces = [ensure_varying(flat, axes) for flat in pieces]
         if b.padded_size > b.size:
             padz = jnp.zeros((b.padded_size - b.size,), F32)
-            pieces.append(ensure_varying(padz, vary) if vary else padz)
+            pieces.append(ensure_varying(padz, axes) if vary else padz)
         out[b.key] = pieces[0] if len(pieces) == 1 else jnp.concatenate(pieces)
     return out
 

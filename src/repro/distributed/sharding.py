@@ -48,7 +48,11 @@ class ShardingRules:
             for cand in candidates:
                 if cand is None:
                     continue
-                if cand in mesh.shape and cand not in taken:
+                # a size-1 axis shards nothing: leaving it out keeps the
+                # value invariant over it, as the collectives (which skip
+                # size-1 groups) expect
+                if cand in mesh.shape and mesh.shape[cand] > 1 \
+                        and cand not in taken:
                     picked.append(cand)
             if not picked:
                 return None

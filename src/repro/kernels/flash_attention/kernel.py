@@ -23,6 +23,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.vma import out_struct
+
 __all__ = ["flash_attention_pallas"]
 
 NEG_INF = -1e30
@@ -131,7 +133,7 @@ def flash_attention_pallas(
             pl.BlockSpec((1, 1, bk, Dv), lambda b, h, i, j, G=G: (b, h // G, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, Dv), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, q.shape[2], Dv), q.dtype),
+        out_shape=out_struct((B, H, q.shape[2], Dv), q.dtype, q, k, v),
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),

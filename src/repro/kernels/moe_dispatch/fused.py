@@ -39,8 +39,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.backends import payload_bytes
 from repro.core.groups import DiompGroup
 from repro.core.rma import dispatch_window_names, ompx_fence, ompx_put
-from repro.core.vma import zeros_varying
-from repro.kernels.plan import AllToAllPlan
+from repro.core.vma import out_struct, zeros_varying
+from repro.kernels.plan import VMEM_LIMIT_BYTES, AllToAllPlan
 from .ref import expert_mlp_ref
 
 __all__ = [
@@ -217,8 +217,8 @@ def _fused_dispatch_kernel(buf_ref, wg_ref, wu_ref, wd_ref, o_ref,
     barrier = pltpu.get_barrier_semaphore()
     for r in range(1, ep):
         pltpu.semaphore_signal(barrier, inc=1,
-                               device_id=(lax.rem(me + r, ep),),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+                               device_id={axis: lax.rem(me + r, ep)},
+                               device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(barrier, ep - 1)
 
     in_flight = {}      # ring offset -> dispatch rdma (my landing from me-s)
@@ -231,8 +231,8 @@ def _fused_dispatch_kernel(buf_ref, wg_ref, wu_ref, wd_ref, o_ref,
                 src_ref=buf_ref.at[lax.rem(me + s, ep)],
                 dst_ref=stage.at[slot],
                 send_sem=send_sems.at[slot], recv_sem=recv_sems.at[slot],
-                device_id=(lax.rem(me + s, ep),),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
+                device_id={axis: lax.rem(me + s, ep)},
+                device_id_type=pltpu.DeviceIdType.MESH)
             rdma.start()
             in_flight[s] = rdma
         elif phase == "fence":
@@ -256,8 +256,8 @@ def _fused_dispatch_kernel(buf_ref, wg_ref, wu_ref, wd_ref, o_ref,
                 dst_ref=o_ref.at[me],
                 send_sem=ret_send_sems.at[slot],
                 recv_sem=ret_recv_sems.at[slot],
-                device_id=(lax.rem(me - s + ep, ep),),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
+                device_id={axis: lax.rem(me - s + ep, ep)},
+                device_id_type=pltpu.DeviceIdType.MESH)
             rdma.start()
             ret_flight[slot] = rdma
         elif phase == "fence_ret":
@@ -271,7 +271,7 @@ def fused_moe_dispatch_tpu(toks, top_e, top_w, wg, wu, wd,
     """The compiled fused kernel (requires a real TPU backend).
 
     Restriction recorded here rather than hidden: the EP group must be a
-    single mesh axis (``device_id`` is the logical index along it).  The
+    single mesh axis (``device_id`` names the peer's index along it).  The
     routing scatter and the gated combine stay outside the kernel (cheap,
     token-local); the kernel owns the overlapped exchange + GEMMs.
     """
@@ -284,9 +284,10 @@ def fused_moe_dispatch_tpu(toks, top_e, top_w, wg, wu, wd,
     full = pl.pallas_call(
         functools.partial(_fused_dispatch_kernel, axis=group.axes[0],
                           plan=plan, slots=slots),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM)] * 4,
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-        out_shape=jax.ShapeDtypeStruct((ep, E_loc, C, d), toks.dtype),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM)] * 4,
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+        out_shape=out_struct((ep, E_loc, C, d), toks.dtype, buf, wg, wu,
+                             wd),
         scratch_shapes=[
             pltpu.VMEM((slots, E_loc, C, d), toks.dtype),
             pltpu.VMEM((slots, E_loc, C, d), toks.dtype),
@@ -295,6 +296,7 @@ def fused_moe_dispatch_tpu(toks, top_e, top_w, wg, wu, wd,
             pltpu.SemaphoreType.DMA((slots,)),
             pltpu.SemaphoreType.DMA((slots,)),
         ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=1),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=1, vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(buf, wg, wu, wd)
     return _combine(full, addr, gates, t_loc, d), dropped

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.vma import out_struct
 from repro.kernels.plan import resolve_interpret
 
 __all__ = ["expert_mlp_pallas"]
@@ -51,6 +52,6 @@ def expert_mlp_pallas(x, wg, wu, wd, *, interpret: Optional[bool] = None):
             pl.BlockSpec((1, f, d), lambda e: (e, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, C, d), lambda e: (e, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((E, C, d), x.dtype),
+        out_shape=out_struct((E, C, d), x.dtype, x, wg, wu, wd),
         interpret=resolve_interpret(interpret),
     )(x, wg, wu, wd)

@@ -47,13 +47,33 @@ __all__ = [
     "split_extents",
 ]
 
-# Per-core VMEM a kernel may plan against.  Real v5e cores have ~16 MiB more,
-# but the compiler needs headroom for spills and the pipeline's own buffers.
+# VMEM limits as the TPU compiler enforces them on a v5e TensorCore, which
+# has 128 MiB of VMEM (``pltpu.get_tpu_info().vmem_capacity_bytes``):
+#
+# * a kernel's stack and pipeline buffers get 16 MiB of "scoped" VMEM unless
+#   the kernel asks for more (the compiler's refusal names this limit:
+#   "Scoped allocation ... limit 16.00M").  Tiled kernels plan against it.
+# * a kernel may raise that limit with ``CompilerParams(vmem_limit_bytes)``;
+#   scratch buffers and the raised limit together must stay inside the
+#   128 MiB.  Kernels that keep whole operands resident ask for
+#   ``VMEM_LIMIT_BYTES``, and their dispatchers route any shape whose
+#   resident bytes exceed it to the XLA path instead.
 VMEM_BUDGET_DEFAULT = 16 * 2**20
+VMEM_LIMIT_BYTES = 100 * 2**20
+
+LANES, SUBLANES = 128, 8   # minor-dim tile of a 32-bit VMEM array
 
 
 def _itemsize(dtype) -> int:
     return jnp.dtype(dtype).itemsize
+
+
+def padded_plane(y: int, x: int, halo: int) -> Tuple[int, int]:
+    """(Y, X) extents of a halo-padded plane rounded up to the VMEM tile:
+    Y + 2·halo to a sublane multiple, X + 2·halo to a lane multiple, so a
+    DMA of whole planes never slices a minor dimension."""
+    return (-(-(y + 2 * halo) // SUBLANES) * SUBLANES,
+            -(-(x + 2 * halo) // LANES) * LANES)
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -842,24 +862,34 @@ class OverlapPlanner:
 
     # -- stencil slab ---------------------------------------------------------
     def plan_stencil_bz(self, z: int, y: int, x: int, dtype,
-                        *, radius: int = 4, bz: int = 8) -> int:
+                        *, radius: int = 4, bz: int = 8,
+                        budget: Optional[int] = None) -> int:
         """Z-slab height whose halo slab still double-buffers in budget.
 
-        Degenerate inputs fall back instead of producing an invalid plan:
-        ``bz`` exceeding the Z extent clamps to it, a grid shorter than the
-        stencil support still yields a positive slab, and a budget too
-        small for any slab bottoms out at ``bz == 1`` (the kernel then
-        streams one plane at a time — slow, never wrong).
+        ``budget`` defaults to the planner's; the streamed stencil kernel
+        passes the VMEM limit it compiles with.  The slab is counted at its
+        tile-padded plane (:func:`padded_plane`).  Degenerate inputs fall
+        back instead of producing an invalid plan: ``bz`` exceeding the Z
+        extent clamps to it, a grid shorter than the stencil support still
+        yields a positive slab, and a budget too small for any slab bottoms
+        out at ``bz == 1`` (the kernel then streams one plane at a time —
+        slow, never wrong).
         """
-        item = _itemsize(dtype)
         bz = max(min(bz, z), 1)
-        while bz > 1:
-            slab = (bz + 2 * radius) * (y + 2 * radius) * (x + 2 * radius)
-            ws = slab * item + 3 * bz * y * x * item   # slab + prev/c2/out blocks
-            if self._fits(ws):
-                break
+        while bz > 1 and not self.stencil_fits(bz, y, x, dtype,
+                                               radius=radius, budget=budget):
             bz = max(1, bz // 2)
         return bz
+
+    def stencil_fits(self, bz: int, y: int, x: int, dtype, *,
+                     radius: int = 4, budget: Optional[int] = None) -> bool:
+        """Does a ``bz``-plane slab (counted at its tile-padded plane) plus
+        the u_prev/velocity/output blocks double-buffer in ``budget``?"""
+        budget = self.vmem_budget if budget is None else budget
+        item = _itemsize(dtype)
+        yp, xp = padded_plane(y, x, radius)
+        ws = ((bz + 2 * radius) * yp * xp + 3 * bz * y * x) * item
+        return self.pool.plan_slots(ws, budget) * ws <= budget
 
     # -- halo exchange (Minimod) ----------------------------------------------
     def plan_halo_slots(self, z_loc: int, y_loc: int, x: int, dtype,

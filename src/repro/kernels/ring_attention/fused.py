@@ -44,7 +44,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.backends import payload_bytes
 from repro.core.groups import DiompGroup
 from repro.core.rma import attention_window_names, ompx_fence, ompx_put
-from repro.kernels.plan import AttentionRingPlan
+from repro.core.vma import out_struct
+from repro.kernels.plan import LANES, VMEM_LIMIT_BYTES, AttentionRingPlan
 from .kernel import chain_grads, empty_state, finalize_state, merge_states, \
     scaled_queries, stripe_mask, stripe_state
 
@@ -235,6 +236,42 @@ def _ring_slots(plan: AttentionRingPlan) -> int:
     return max(plan.slots, need)
 
 
+def _fold_stripe(q2, k2, v2, m, l, acc, *, q0, tq: int, k_start,
+                 causal: bool, valid_len):
+    """One (batch, kv-head) pair's queries against one stripe, merged into
+    its (m, l, acc) carry — :func:`~.kernel.stripe_state` then
+    :func:`~.kernel.merge_states` (``exact=False``) on 2-D operands, so
+    Mosaic sees plain matmuls.
+
+    ``q2 (G·tq, D)`` pre-scaled f32 queries, row ``g·tq + t`` at position
+    ``q0 + t``; ``k2/v2 (tk, D/Dv)``; ``m, l (G·tq, 1)``; ``acc (G·tq, Dv)``.
+    """
+    f32 = jnp.float32
+    s = lax.dot_general(q2, k2.astype(f32), (((1,), (1,)), ((), ())),
+                        preferred_element_type=f32)
+    k_pos = k_start + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    vis = None
+    if valid_len is not None:
+        vis = k_pos < valid_len
+    if causal:
+        q_pos = q0 + lax.rem(lax.broadcasted_iota(jnp.int32, s.shape, 0), tq)
+        vis = k_pos <= q_pos if vis is None else vis & (k_pos <= q_pos)
+    if vis is not None:
+        s = jnp.where(vis, s, -jnp.inf)
+    mb = s.max(axis=-1, keepdims=True)           # -inf on fully masked rows
+    p = jnp.exp(s - jnp.where(mb == -jnp.inf, 0.0, mb))
+    if vis is not None:
+        p = jnp.where(vis, p, 0.0)
+    lb = p.sum(axis=-1, keepdims=True)
+    ab = lax.dot_general(p, v2.astype(f32), (((1,), (0,)), ((), ())),
+                         preferred_element_type=f32)
+    mn = jnp.maximum(m, mb)
+    mn_safe = jnp.where(mn == -jnp.inf, 0.0, mn)
+    c1 = jnp.where(m == -jnp.inf, 0.0, jnp.exp(m - mn_safe))
+    c2 = jnp.where(mb == -jnp.inf, 0.0, jnp.exp(mb - mn_safe))
+    return mn, l * c1 + lb * c2, acc * c1 + ab * c2
+
+
 def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
                             kbufs, vbufs, macc, lacc, oacc,
                             ksend, krecv, vsend, vrecv,
@@ -242,16 +279,19 @@ def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
                             scale: float):
     """Kernel body; the schedule is baked statically, ranks are traced.
 
-    ``kbufs/vbufs``: VMEM (2, slots, B, tk_loc, KH, D/Dv) stripe slots per
-    direction (0 = clockwise, 1 = counter-clockwise); ``macc/lacc/oacc``
-    the f32 (m, l, acc) merge carry.  Step ``s + 1``'s RDMAs start before
-    step ``s``'s flash blocks; ``pl.when`` skips the blocks of stripes the
+    Head-major operands: ``q_ref (B, KH, G·tq, D)`` (GQA groups stacked
+    along the rows), ``k_ref/v_ref (B, KH, tk, D/Dv)``.  ``kbufs/vbufs``:
+    VMEM (2, slots, B, KH, tk, D/Dv) stripe slots per direction
+    (0 = clockwise, 1 = counter-clockwise); ``macc/lacc/oacc`` the f32
+    (m, l, acc) merge carry.  Step ``s + 1``'s RDMAs start before step
+    ``s``'s stripe folds; ``pl.when`` skips the folds of stripes the
     causal plan proves fully masked (their states are the merge identity,
-    so the carry is bit-identical to the non-skipping emulation).
+    so the carry equals the non-skipping emulation's).
     """
     n, slots = plan.n, _ring_slots(plan)
-    B, tq, H, D = q_ref.shape
-    tk = k_ref.shape[1]
+    B, KH, rows, _ = q_ref.shape
+    tq = rows // (plan.h // plan.kh)
+    tk = k_ref.shape[2]
     me = lax.axis_index(axis)
     right = lax.rem(me + 1, n)
     left = lax.rem(me + n - 1, n)
@@ -260,10 +300,10 @@ def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
         # startup barrier: both neighbors entered the kernel before any
         # RDMA touches their buffers (slot 0 is seeded locally)
         barrier = pltpu.get_barrier_semaphore()
-        pltpu.semaphore_signal(barrier, inc=1, device_id=(left,),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
-        pltpu.semaphore_signal(barrier, inc=1, device_id=(right,),
-                               device_id_type=pltpu.DeviceIdType.LOGICAL)
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis: left},
+                               device_id_type=pltpu.DeviceIdType.MESH)
+        pltpu.semaphore_signal(barrier, inc=1, device_id={axis: right},
+                               device_id_type=pltpu.DeviceIdType.MESH)
         pltpu.semaphore_wait(barrier, 2)
 
         kbufs[0, 0] = k_ref[...]
@@ -271,26 +311,27 @@ def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
         vbufs[0, 0] = v_ref[...]
         vbufs[1, 0] = v_ref[...]
 
-    qg = scaled_queries(q_ref[...], plan.kh, scale)
     q0 = (jnp.int32(plan.q_offset or 0)
           + (me * tq if plan.q_sharded else 0))
-    q_pos = jnp.reshape(q0, (-1, 1)) + jnp.arange(tq)[None, :]
-    m0, l0, a0 = empty_state(qg, v_ref[...])
-    macc[...] = m0
-    lacc[...] = l0
-    oacc[...] = a0
+    macc[...] = jnp.full(macc.shape, -jnp.inf, jnp.float32)
+    lacc[...] = jnp.zeros(lacc.shape, jnp.float32)
+    oacc[...] = jnp.zeros(oacc.shape, jnp.float32)
 
     def fold(stream: int, slot: int, src):
-        k_str = k_ref[...] if n == 1 else kbufs[stream, slot]
-        v_str = v_ref[...] if n == 1 else vbufs[stream, slot]
-        blk = stripe_state(qg, k_str, v_str, q_pos=q_pos, k_start=src * tk,
-                           causal=plan.causal, valid_len=plan.valid_len,
-                           exact=False)
-        m, l, a = merge_states((macc[...], lacc[...], oacc[...]), blk,
-                               exact=False)
-        macc[...] = m
-        lacc[...] = l
-        oacc[...] = a
+        def pair(i, carry):
+            b, h = i // KH, i % KH
+            k2 = k_ref[b, h] if n == 1 else kbufs[stream, slot, b, h]
+            v2 = v_ref[b, h] if n == 1 else vbufs[stream, slot, b, h]
+            m, l, a = _fold_stripe(
+                q_ref[b, h].astype(jnp.float32) * scale, k2, v2,
+                macc[b, h], lacc[b, h], oacc[b, h], q0=q0, tq=tq,
+                k_start=src * tk, causal=plan.causal,
+                valid_len=plan.valid_len)
+            macc[b, h] = m
+            lacc[b, h] = l
+            oacc[b, h] = a
+            return carry
+        lax.fori_loop(0, B * KH, pair, 0)
 
     def wanted(src):
         # the traced twin of plan.computes(me, src): skip only stripes the
@@ -313,8 +354,8 @@ def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
                 rdma = pltpu.make_async_remote_copy(
                     src_ref=bufs.at[0, slot], dst_ref=bufs.at[0, nxt],
                     send_sem=ss.at[0, slot], recv_sem=rs.at[0, nxt],
-                    device_id=(right,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL)
+                    device_id={axis: right},
+                    device_id_type=pltpu.DeviceIdType.MESH)
                 rdma.start()
                 rdmas.append(rdma)
         if st.send_ccw:   # my ccw stripes -> left neighbor's next ccw slots
@@ -323,12 +364,12 @@ def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
                 rdma = pltpu.make_async_remote_copy(
                     src_ref=bufs.at[1, slot], dst_ref=bufs.at[1, nxt],
                     send_sem=ss.at[1, slot], recv_sem=rs.at[1, nxt],
-                    device_id=(left,),
-                    device_id_type=pltpu.DeviceIdType.LOGICAL)
+                    device_id={axis: left},
+                    device_id_type=pltpu.DeviceIdType.MESH)
                 rdma.start()
                 rdmas.append(rdma)
 
-        # flash blocks on the CURRENT slots overlap the in-flight stripes
+        # stripe folds on the CURRENT slots overlap the in-flight stripes
         if st.compute_cw:
             src = lax.rem(me - st.index + n, n)
             pl.when(wanted(src))(lambda s=slot, r=src: fold(0, s, r))
@@ -339,8 +380,26 @@ def _fused_attention_kernel(q_ref, k_ref, v_ref, o_ref,
         for rdma in rdmas:    # next step's stripes must have landed
             rdma.wait()
 
-    o_ref[...] = finalize_state((macc[...], lacc[...], oacc[...]),
-                                o_ref.dtype, exact=False)
+    # finalize (``finalize_state``, exact=False)
+    o_ref[...] = (oacc[...] / jnp.maximum(lacc[...], 1e-30)).astype(
+        o_ref.dtype)
+
+
+def _lanes(d: int) -> int:
+    return -(-d // LANES) * LANES
+
+
+def fused_ring_attention_resident_bytes(plan: AttentionRingPlan,
+                                        dtype) -> int:
+    """VMEM the compiled kernel holds at once: q, k, v and output, the
+    stripe slots of both directions, and the f32 merge carry (``m``/``l``
+    one lane-padded column per query row), head dims padded to lanes."""
+    item = jnp.dtype(dtype).itemsize
+    d, dv = _lanes(plan.d), _lanes(plan.dv)
+    rows = plan.b * plan.h * plan.tq_loc
+    kv = plan.b * plan.kh * plan.tk_loc * (d + dv)
+    return ((rows * (d + dv) + (1 + 2 * _ring_slots(plan)) * kv) * item
+            + rows * (2 * LANES + dv) * 4)
 
 
 def fused_ring_attention_tpu(q, k, v, *, axis: str,
@@ -348,9 +407,16 @@ def fused_ring_attention_tpu(q, k, v, *, axis: str,
     """The compiled fused kernel (requires a real TPU backend).
 
     Restrictions recorded here rather than hidden: the ring must be a
-    single mesh axis (``device_id`` is the logical index along it), and
-    the kernel needs STATIC ``q_offset``/``valid_len`` (they are plan
-    fields baked into the masks; traced offsets route to the emulation).
+    single mesh axis (``device_id`` names the peer's index along it), the
+    kernel needs STATIC ``q_offset``/``valid_len`` (they are plan fields
+    baked into the masks; traced offsets route to the emulation), and
+    every operand stays resident in VMEM
+    (:func:`fused_ring_attention_resident_bytes` must fit
+    ``VMEM_LIMIT_BYTES``; the dispatcher routes larger shapes to the
+    emulation).  The kernel works head-major, with the head dims zero-padded
+    to whole lanes (a remote copy cannot slice a partial lane tile; zero
+    columns change neither ``q·k`` nor the kept ``Dv`` outputs, but they do
+    ride the wire).  The layout changes happen here, outside the kernel.
     """
     B, tq, H, D = q.shape
     tk, KH = k.shape[1], k.shape[2]
@@ -359,26 +425,39 @@ def fused_ring_attention_tpu(q, k, v, *, axis: str,
         scale = D ** -0.5
     slots = _ring_slots(plan)
     G = H // KH
-    return pl.pallas_call(
+    Dp, Dvp = _lanes(D), _lanes(Dv)
+
+    def lanes(x, width):
+        pad = width - x.shape[-1]
+        return x if not pad else jnp.pad(x, ((0, 0),) * 3 + ((0, pad),))
+
+    qh = lanes(q.reshape(B, tq, KH, G, D).transpose(0, 2, 3, 1, 4).reshape(
+        B, KH, G * tq, D), Dp)
+    kh = lanes(k.transpose(0, 2, 1, 3), Dp)
+    vh = lanes(v.transpose(0, 2, 1, 3), Dvp)
+    out = pl.pallas_call(
         functools.partial(_fused_attention_kernel, axis=axis, plan=plan,
                           scale=scale),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-        out_shape=jax.ShapeDtypeStruct((B, tq, H, Dv), q.dtype),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+        out_shape=out_struct((B, KH, G * tq, Dvp), q.dtype, qh, kh, vh),
         scratch_shapes=[
-            pltpu.VMEM((2, slots, B, tk, KH, D), k.dtype),
-            pltpu.VMEM((2, slots, B, tk, KH, Dv), v.dtype),
-            pltpu.VMEM((B, tq, KH, G), jnp.float32),
-            pltpu.VMEM((B, tq, KH, G), jnp.float32),
-            pltpu.VMEM((B, tq, KH, G, Dv), jnp.float32),
+            pltpu.VMEM((2, slots, B, KH, tk, Dp), k.dtype),
+            pltpu.VMEM((2, slots, B, KH, tk, Dvp), v.dtype),
+            pltpu.VMEM((B, KH, G * tq, 1), jnp.float32),
+            pltpu.VMEM((B, KH, G * tq, 1), jnp.float32),
+            pltpu.VMEM((B, KH, G * tq, Dvp), jnp.float32),
             pltpu.SemaphoreType.DMA((2, slots)),
             pltpu.SemaphoreType.DMA((2, slots)),
             pltpu.SemaphoreType.DMA((2, slots)),
             pltpu.SemaphoreType.DMA((2, slots)),
         ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=2),
-    )(q, k, v)
+        compiler_params=pltpu.CompilerParams(
+            collective_id=2, vmem_limit_bytes=VMEM_LIMIT_BYTES),
+    )(qh, kh, vh)
+    return out[..., :Dv].reshape(B, KH, G, tq, Dv).transpose(
+        0, 3, 1, 2, 4).reshape(B, tq, H, Dv)

@@ -60,6 +60,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.backends import ensure_varying
+
 __all__ = [
     "scaled_queries",
     "empty_state",
@@ -146,18 +148,26 @@ def _np_stripe_bwd(qg, k, v, mask, gl, gacc):
     return gqg, gk, gv
 
 
+def _callback(fn, shapes, *args):
+    """``fn`` on the host with f32 results of ``shapes``.  Under shard_map
+    a callback's results vary over no mesh axis; they are promoted to vary
+    over every axis its arguments vary over, as the values they stand for
+    do."""
+    vma = frozenset().union(*(jax.typeof(a).vma for a in args))
+    out = jax.pure_callback(
+        fn, tuple(jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes),
+        *args)
+    return ensure_varying(out, tuple(sorted(vma)))
+
+
 def _state_shapes(qg, v):
     B, Tq, KH, G, _ = qg.shape
-    sd = jax.ShapeDtypeStruct
-    return (sd((B, Tq, KH, G), jnp.float32),
-            sd((B, Tq, KH, G), jnp.float32),
-            sd((B, Tq, KH, G, v.shape[-1]), jnp.float32))
+    return (B, Tq, KH, G), (B, Tq, KH, G), (B, Tq, KH, G, v.shape[-1])
 
 
 @jax.custom_vjp
 def _stripe_exact(qg, k32, v32, mask):
-    return jax.pure_callback(_np_stripe, _state_shapes(qg, v32),
-                             qg, k32, v32, mask)
+    return _callback(_np_stripe, _state_shapes(qg, v32), qg, k32, v32, mask)
 
 
 def _stripe_exact_fwd(qg, k32, v32, mask):
@@ -167,11 +177,8 @@ def _stripe_exact_fwd(qg, k32, v32, mask):
 def _stripe_exact_bwd(res, ct):
     qg, k32, v32, mask = res
     _, gl, gacc = ct                         # gm dies here (see module doc)
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(qg.shape, jnp.float32), sd(k32.shape, jnp.float32),
-              sd(v32.shape, jnp.float32))
-    gqg, gk, gv = jax.pure_callback(_np_stripe_bwd, shapes,
-                                    qg, k32, v32, mask, gl, gacc)
+    gqg, gk, gv = _callback(_np_stripe_bwd, (qg.shape, k32.shape, v32.shape),
+                            qg, k32, v32, mask, gl, gacc)
     return gqg, gk, gv, jnp.zeros_like(mask)
 
 
@@ -266,10 +273,7 @@ def _np_merge_bwd(m1, m2, gl, gacc):
 @jax.custom_vjp
 def _merge_exact(a: State, b: State) -> State:
     m1, l1, a1 = a
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(m1.shape, jnp.float32), sd(l1.shape, jnp.float32),
-              sd(a1.shape, jnp.float32))
-    return jax.pure_callback(_np_merge, shapes, *a, *b)
+    return _callback(_np_merge, (m1.shape, l1.shape, a1.shape), *a, *b)
 
 
 def _merge_exact_fwd(a, b):
@@ -279,11 +283,7 @@ def _merge_exact_fwd(a, b):
 def _merge_exact_bwd(res, ct):
     m1, m2 = res
     _, gl, gacc = ct                         # gm dies here (see module doc)
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(gl.shape, jnp.float32), sd(gl.shape, jnp.float32),
-              sd(gacc.shape, jnp.float32), sd(gacc.shape, jnp.float32))
-    gl1, gl2, ga1, ga2 = jax.pure_callback(_np_merge_bwd, shapes,
-                                           m1, m2, gl, gacc)
+    gl1, gl2, ga1, ga2 = merge_bwd(m1, m2, gl, gacc)
     return (jnp.zeros_like(m1), gl1, ga1), (jnp.zeros_like(m2), gl2, ga2)
 
 
@@ -329,8 +329,7 @@ def _np_finalize_bwd(l, acc, ct):
 @jax.custom_vjp
 def _finalize_exact(state: State):
     m, l, acc = state
-    return jax.pure_callback(
-        _np_finalize, jax.ShapeDtypeStruct(acc.shape, jnp.float32), l, acc)
+    return _callback(_np_finalize, (acc.shape,), l, acc)[0]
 
 
 def _finalize_exact_fwd(state):
@@ -340,9 +339,7 @@ def _finalize_exact_fwd(state):
 
 def _finalize_exact_bwd(res, ct):
     l, acc = res
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(l.shape, jnp.float32), sd(acc.shape, jnp.float32))
-    gl, gacc = jax.pure_callback(_np_finalize_bwd, shapes, l, acc, ct)
+    gl, gacc = finalize_bwd(ct, l, acc)
     return ((jnp.zeros_like(l), gl, gacc),)
 
 
@@ -379,18 +376,14 @@ def finalize_state(state: State, dtype, exact: bool = True) -> jnp.ndarray:
 def finalize_bwd(ct, l, acc):
     """Cotangents ``(gl, gacc)`` of :func:`finalize_state`'s exact
     normalize for output cotangent ``ct (B, Tq, KH, G, Dv)`` f32."""
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(l.shape, jnp.float32), sd(acc.shape, jnp.float32))
-    return jax.pure_callback(_np_finalize_bwd, shapes, l, acc, ct)
+    return _callback(_np_finalize_bwd, (l.shape, acc.shape), l, acc, ct)
 
 
 def merge_bwd(m1, m2, gl, gacc):
     """Cotangents ``(gl1, gl2, gacc1, gacc2)`` of one exact merge, from
     the two sides' row maxes (the only residual the rescale needs)."""
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(gl.shape, jnp.float32), sd(gl.shape, jnp.float32),
-              sd(gacc.shape, jnp.float32), sd(gacc.shape, jnp.float32))
-    return jax.pure_callback(_np_merge_bwd, shapes, m1, m2, gl, gacc)
+    return _callback(_np_merge_bwd, (gl.shape, gl.shape, gacc.shape,
+                                     gacc.shape), m1, m2, gl, gacc)
 
 
 def stripe_bwd(qg, k_stripe, v_stripe, vis, gl, gacc):
@@ -399,11 +392,8 @@ def stripe_bwd(qg, k_stripe, v_stripe, vis, gl, gacc):
     v32 = v_stripe.astype(jnp.float32)
     mask = jnp.broadcast_to(vis, (qg.shape[0], qg.shape[1],
                                   k_stripe.shape[1])).astype(jnp.float32)
-    sd = jax.ShapeDtypeStruct
-    shapes = (sd(qg.shape, jnp.float32), sd(k32.shape, jnp.float32),
-              sd(v32.shape, jnp.float32))
-    return jax.pure_callback(_np_stripe_bwd, shapes,
-                             qg, k32, v32, mask, gl, gacc)
+    return _callback(_np_stripe_bwd, (qg.shape, k32.shape, v32.shape),
+                     qg, k32, v32, mask, gl, gacc)
 
 
 def chain_grads(qg, stripes, ct):

@@ -7,7 +7,9 @@ conventions apply: ``plan=None`` asks the shared
 (``StreamPool.plan_slots`` contract), ``impl`` resolves ``"auto"``/None to
 the ``"fused"`` overlap order (``"host"`` is the serialized listing), and
 ``interpret=None`` resolves from the backend at call time — compiled on
-TPU, the differentiable ``ompx_put`` emulation elsewhere.
+TPU, the differentiable ``ompx_put`` emulation elsewhere.  Shapes whose
+VMEM-resident bytes exceed ``VMEM_LIMIT_BYTES`` take the emulation on TPU
+too (XLA compiles it there).
 
 Traced ``q_offset``/``valid_len`` (dynamic chunked prefill) are legal:
 the plan then disables static causal step-skipping and the masks handle
@@ -25,9 +27,11 @@ from typing import Optional
 import jax
 
 from repro.core.groups import DiompGroup
-from repro.kernels.plan import AttentionRingPlan, default_planner, \
-    resolve_interpret
-from .fused import fused_ring_attention_interpret, fused_ring_attention_tpu
+from repro.kernels.plan import VMEM_LIMIT_BYTES, AttentionRingPlan, \
+    default_planner, resolve_interpret
+from .fused import (fused_ring_attention_interpret,
+                    fused_ring_attention_resident_bytes,
+                    fused_ring_attention_tpu)
 
 __all__ = ["ring_attention", "resolve_attention_impl"]
 
@@ -82,7 +86,8 @@ def ring_attention(
         raise ValueError(f"plan for n={plan.n} used on a ring of {n}")
     if plan.overlap != (mode == "fused"):
         plan = dataclasses.replace(plan, overlap=mode == "fused")
-    if resolve_interpret(interpret):
+    if resolve_interpret(interpret) or fused_ring_attention_resident_bytes(
+            plan, q.dtype) > VMEM_LIMIT_BYTES:
         return fused_ring_attention_interpret(
             q, k, v, group, plan=plan, scale=scale,
             q_offset=q_offset, valid_len=valid_len)

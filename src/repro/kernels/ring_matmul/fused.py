@@ -32,7 +32,6 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional
 
-import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
@@ -40,12 +39,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.groups import DiompGroup
 from repro.core.rma import ompx_put
-from repro.core.vma import zeros_varying
-from repro.kernels.plan import RingPlan, default_planner, resolve_interpret
+from repro.core.vma import out_struct, zeros_varying
+from repro.kernels.plan import (VMEM_LIMIT_BYTES, RingPlan, default_planner,
+                                resolve_interpret)
 from .ref import matmul_ref
 
 __all__ = [
     "fused_ring_allgather_matmul",
+    "fused_ring_resident_bytes",
     "fused_ring_allgather_matmul_interpret",
     "fused_ring_allgather_matmul_tpu",
 ]
@@ -94,10 +95,10 @@ def _fused_ring_kernel(x_ref, w_ref, o_ref, bufs, send_sems, recv_sems,
     # touches their buffers (over-signaling from a fast neighbor is benign
     # here — slot 0 is seeded locally, never remotely written)
     barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(left,),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(right,),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
+    pltpu.semaphore_signal(barrier, inc=1, device_id={axis: left},
+                           device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_signal(barrier, inc=1, device_id={axis: right},
+                           device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(barrier, 2)
 
     # seed both streams' slot 0 with the local stripe
@@ -119,16 +120,16 @@ def _fused_ring_kernel(x_ref, w_ref, o_ref, bufs, send_sems, recv_sems,
             rdma = pltpu.make_async_remote_copy(
                 src_ref=bufs.at[0, slot], dst_ref=bufs.at[0, nxt],
                 send_sem=send_sems.at[0, slot], recv_sem=recv_sems.at[0, nxt],
-                device_id=(right,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
+                device_id={axis: right},
+                device_id_type=pltpu.DeviceIdType.MESH)
             rdma.start()
             rdmas.append(rdma)
         if st.send_ccw:       # my ccw stripe -> left neighbor's next ccw slot
             rdma = pltpu.make_async_remote_copy(
                 src_ref=bufs.at[1, slot], dst_ref=bufs.at[1, nxt],
                 send_sem=send_sems.at[1, slot], recv_sem=recv_sems.at[1, nxt],
-                device_id=(left,),
-                device_id_type=pltpu.DeviceIdType.LOGICAL)
+                device_id={axis: left},
+                device_id_type=pltpu.DeviceIdType.MESH)
             rdma.start()
             rdmas.append(rdma)
 
@@ -142,12 +143,26 @@ def _fused_ring_kernel(x_ref, w_ref, o_ref, bufs, send_sems, recv_sems,
             rdma.wait()
 
 
+def fused_ring_resident_bytes(t_loc: int, k: int, n_loc: int, dtype,
+                              plan: RingPlan) -> int:
+    """VMEM the compiled kernel holds at once: the stripe slots of both
+    streams, the resident X stripe, W block and (n·t_loc, n_loc) output,
+    and one step's f32 GEMM result."""
+    item = jnp.dtype(dtype).itemsize
+    slots = _ring_slots(plan)
+    return ((2 * slots + 1) * t_loc * k + k * n_loc
+            + plan.n * t_loc * n_loc) * item + t_loc * n_loc * 4
+
+
 def fused_ring_allgather_matmul_tpu(x_local, w_local, *, axis: str,
                                     plan: RingPlan):
     """The compiled fused kernel (requires a real TPU backend).
 
-    Restriction recorded here rather than hidden: the ring must be a single
-    mesh axis (``device_id`` is the logical index along it).
+    Restrictions recorded here rather than hidden: the ring must be a single
+    mesh axis (``device_id`` names the peer's index along it), and every
+    operand stays resident in VMEM (:func:`fused_ring_resident_bytes` must
+    fit ``VMEM_LIMIT_BYTES``; the dispatcher routes larger shapes to the
+    emulation).
     """
     t_loc, k = x_local.shape
     n_loc = w_local.shape[1]
@@ -156,17 +171,19 @@ def fused_ring_allgather_matmul_tpu(x_local, w_local, *, axis: str,
         functools.partial(_fused_ring_kernel, axis=axis, plan=plan,
                           t_loc=t_loc),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-        out_shape=jax.ShapeDtypeStruct((plan.n * t_loc, n_loc), x_local.dtype),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+        out_shape=out_struct((plan.n * t_loc, n_loc), x_local.dtype,
+                             x_local, w_local),
         scratch_shapes=[
             pltpu.VMEM((2, slots, t_loc, k), x_local.dtype),
             pltpu.SemaphoreType.DMA((2, slots)),
             pltpu.SemaphoreType.DMA((2, slots)),
         ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=0),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=0, vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(x_local, w_local)
 
 
@@ -231,8 +248,9 @@ def fused_ring_allgather_matmul(
     traced shapes; ``interpret=None`` resolves from the backend at call
     time (compiled on TPU, emulated elsewhere).  A caller-supplied ``dot``
     carries custom GEMM semantics the in-kernel ``lax.dot_general`` cannot
-    honor, so it always routes through the emulation — which XLA still
-    compiles (and overlaps) on TPU.
+    honor, and shapes whose resident bytes exceed ``VMEM_LIMIT_BYTES``
+    cannot be held by the kernel; both route through the emulation — which
+    XLA still compiles (and overlaps) on TPU.
     """
     from repro.core.compat import axis_size
 
@@ -243,7 +261,11 @@ def fused_ring_allgather_matmul(
             x_local.dtype, n, direction=direction)
     if plan.n != n:
         raise ValueError(f"plan for n={plan.n} used on a ring of {n}")
-    if resolve_interpret(interpret) or dot is not None:
+    resident = fused_ring_resident_bytes(
+        x_local.shape[0], x_local.shape[1], w_local.shape[1], x_local.dtype,
+        plan)
+    if resolve_interpret(interpret) or dot is not None \
+            or resident > VMEM_LIMIT_BYTES:
         return fused_ring_allgather_matmul_interpret(
             x_local, w_local, group, plan=plan, dot=dot)
     return fused_ring_allgather_matmul_tpu(

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.vma import out_struct
+
 __all__ = ["matmul_pallas"]
 
 
@@ -59,7 +61,7 @@ def matmul_pallas(x, w, *, bm: int = 256, bk: int = 512, bn: int = 256,
             pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
+        out_shape=out_struct((Mp, Np), x.dtype, x, w),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
     )(x, w)

@@ -52,14 +52,17 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.backends import payload_bytes
 from repro.core.groups import DiompGroup
 from repro.core.rma import RMAError, halo_window_names, ompx_fence, ompx_put
-from repro.core.vma import zeros_varying
-from repro.kernels.plan import HaloPlan, default_planner, resolve_interpret
+from repro.core.vma import out_struct, zeros_varying
+from repro.kernels.plan import (LANES, SUBLANES, VMEM_LIMIT_BYTES, HaloPlan,
+                                default_planner, resolve_interpret)
+from .kernel import leap_plane
 from .ref import COEFFS, RADIUS
 
 __all__ = [
     "Halos",
     "exchange_halos",
     "fused_wave_step",
+    "fused_resident_bytes",
     "fused_wave_step_interpret",
     "fused_wave_step_tpu",
 ]
@@ -368,11 +371,25 @@ def fused_wave_step_interpret(
 # ---------------------------------------------------------------------------
 
 
-def _fused_stencil_kernel(u_ref, uprev_ref, c2_ref, o_ref, halo_bufs,
+# the halo-extended field's core starts on a tile boundary (one sublane
+# tile above it, one lane tile left of it), so it is stored aligned and only
+# the star's shifted reads are unaligned
+_OY, _OX = SUBLANES, LANES
+
+
+def _ext_shape(Z: int, Y: int, X: int, R: int) -> Tuple[int, int, int]:
+    """VMEM shape of the fused kernel's halo-extended field."""
+    return (Z + 2 * R, _OY + -(-(Y + R) // SUBLANES) * SUBLANES,
+            _OX + -(-(X + R) // LANES) * LANES)
+
+
+def _fused_stencil_kernel(u_ref, uprev_ref, c2_ref, o_ref, ext, halo_bufs,
                           send_sems, recv_sems, *, axis: str, plan: HaloPlan,
                           dx: float):
     """Kernel body; the phase order is baked statically, ranks are traced.
 
+    ``ext``: VMEM halo-extended field (Dirichlet zeros around the shard,
+    the neighbors' slabs in its first/last R planes once they land).
     ``halo_bufs``: VMEM (2, R, Y, X) landing windows — slot 0 receives the
     down-neighbor's hi slab (my lo halo), slot 1 the up-neighbor's lo slab.
     Like the emulation, the puts run the full ring and the wrap-around edge
@@ -383,9 +400,19 @@ def _fused_stencil_kernel(u_ref, uprev_ref, c2_ref, o_ref, halo_bufs,
     Z, Y, X = u_ref.shape
     dtype = o_ref.dtype
 
+    def planes(lo, hi):
+        def body(z, carry):
+            o_ref[z] = leap_plane(ext, z + R, uprev_ref[z], c2_ref[z],
+                                  oy=_OY, ox=_OX, Y=Y, X=X,
+                                  dx=dx).astype(dtype)
+            return carry
+        lax.fori_loop(lo, hi, body, 0)
+
+    ext[...] = jnp.zeros(ext.shape, ext.dtype)
+    ext[pl.ds(R, Z), pl.ds(_OY, Y), pl.ds(_OX, X)] = u_ref[...]
+
     if nz == 1:       # whole axis local: pure Dirichlet, no comm at all
-        o_ref[...] = _leap(jnp.pad(u_ref[...], R), uprev_ref[...],
-                           c2_ref[...], dx=dx, dtype=dtype)
+        planes(0, Z)
         return
 
     me = lax.axis_index(axis)
@@ -395,10 +422,10 @@ def _fused_stencil_kernel(u_ref, uprev_ref, c2_ref, o_ref, halo_bufs,
     # startup barrier: both neighbors entered the kernel before any RDMA
     # touches their landing windows
     barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(down,),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
-    pltpu.semaphore_signal(barrier, inc=1, device_id=(up,),
-                           device_id_type=pltpu.DeviceIdType.LOGICAL)
+    pltpu.semaphore_signal(barrier, inc=1, device_id={axis: down},
+                           device_id_type=pltpu.DeviceIdType.MESH)
+    pltpu.semaphore_signal(barrier, inc=1, device_id={axis: up},
+                           device_id_type=pltpu.DeviceIdType.MESH)
     pltpu.semaphore_wait(barrier, 2)
 
     # phase "put": one-sided deposits of my boundary slabs — my hi slab is
@@ -406,41 +433,40 @@ def _fused_stencil_kernel(u_ref, uprev_ref, c2_ref, o_ref, halo_bufs,
     rdma_hi = pltpu.make_async_remote_copy(
         src_ref=u_ref.at[pl.ds(Z - R, R)], dst_ref=halo_bufs.at[0],
         send_sem=send_sems.at[0], recv_sem=recv_sems.at[0],
-        device_id=(up,), device_id_type=pltpu.DeviceIdType.LOGICAL)
+        device_id={axis: up}, device_id_type=pltpu.DeviceIdType.MESH)
     rdma_lo = pltpu.make_async_remote_copy(
         src_ref=u_ref.at[pl.ds(0, R)], dst_ref=halo_bufs.at[1],
         send_sem=send_sems.at[1], recv_sem=recv_sems.at[1],
-        device_id=(down,), device_id_type=pltpu.DeviceIdType.LOGICAL)
+        device_id={axis: down}, device_id_type=pltpu.DeviceIdType.MESH)
     rdma_hi.start()
     rdma_lo.start()
 
-    # phase "interior": the halo-independent slab computes under the wire
-    u = u_ref[...]
-    upad = jnp.pad(u, R)
+    # phase "interior": the halo-independent planes compute under the wire
     if plan.overlap:
-        o_ref[pl.ds(R, Z - 2 * R)] = _leap(
-            upad[R:Z + R], uprev_ref[pl.ds(R, Z - 2 * R)],
-            c2_ref[pl.ds(R, Z - 2 * R)], dx=dx, dtype=dtype)
+        planes(R, Z - R)
 
     # phase "fence": the neighbor slabs must have landed
     rdma_hi.wait()
     rdma_lo.wait()
 
     # phase "boundary": edge ranks see Dirichlet zeros, not the wrap-around
-    lo_halo = jnp.where(me == 0, jnp.zeros_like(halo_bufs[0]), halo_bufs[0])
-    hi_halo = jnp.where(me == nz - 1, jnp.zeros_like(halo_bufs[1]),
-                        halo_bufs[1])
-    uext = upad.at[0:R, R:Y + R, R:X + R].set(lo_halo)
-    uext = uext.at[Z + R:Z + 2 * R, R:Y + R, R:X + R].set(hi_halo)
+    ext[pl.ds(0, R), pl.ds(_OY, Y), pl.ds(_OX, X)] = jnp.where(
+        me == 0, jnp.zeros_like(halo_bufs[0]), halo_bufs[0])
+    ext[pl.ds(Z + R, R), pl.ds(_OY, Y), pl.ds(_OX, X)] = jnp.where(
+        me == nz - 1, jnp.zeros_like(halo_bufs[1]), halo_bufs[1])
     if plan.overlap:
-        o_ref[pl.ds(0, R)] = _leap(uext[0:3 * R], uprev_ref[pl.ds(0, R)],
-                                   c2_ref[pl.ds(0, R)], dx=dx, dtype=dtype)
-        o_ref[pl.ds(Z - R, R)] = _leap(
-            uext[Z - R:Z + 2 * R], uprev_ref[pl.ds(Z - R, R)],
-            c2_ref[pl.ds(Z - R, R)], dx=dx, dtype=dtype)
+        planes(0, R)
+        planes(Z - R, Z)
     else:             # degenerate grid: everything is boundary
-        o_ref[...] = _leap(uext, uprev_ref[...], c2_ref[...], dx=dx,
-                           dtype=dtype)
+        planes(0, Z)
+
+
+def fused_resident_bytes(Z: int, Y: int, X: int, dtype, halo: int) -> int:
+    """VMEM the compiled fused step holds at once: u, u_prev, velocity and
+    output shards, the halo landing windows and the extended field."""
+    item = jnp.dtype(dtype).itemsize
+    ez, ey, ex = _ext_shape(Z, Y, X, halo)
+    return (4 * Z * Y * X + 2 * halo * Y * X + ez * ey * ex) * item
 
 
 def fused_wave_step_tpu(u, u_prev, c2dt2, *, axis: str, plan: HaloPlan,
@@ -451,9 +477,10 @@ def fused_wave_step_tpu(u, u_prev, c2dt2, *, axis: str, plan: HaloPlan,
     symmetric extents (2-D, asymmetric and carried-halo configurations
     route through the emulation, which XLA compiles and overlaps on TPU);
     the ring must be a single mesh axis; the whole shard is staged resident
-    in VMEM (the dispatcher routes shards that don't fit to the emulation —
-    the HaloPlan's bz/by staging pipeline describes the emulation's XLA
-    fusion window, not this kernel's residency).
+    in VMEM (the dispatcher routes shards whose
+    :func:`fused_resident_bytes` exceed ``VMEM_LIMIT_BYTES`` to the
+    emulation — the HaloPlan's bz/by staging pipeline describes the
+    emulation's XLA fusion window, not this kernel's residency).
     """
     Z, Y, X = u.shape
     R = plan.halo
@@ -461,18 +488,20 @@ def fused_wave_step_tpu(u, u_prev, c2dt2, *, axis: str, plan: HaloPlan,
     return pl.pallas_call(
         functools.partial(_fused_stencil_kernel, axis=axis, plan=plan, dx=dx),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
         ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.VMEM),
-        out_shape=jax.ShapeDtypeStruct((Z, Y, X), u.dtype),
+        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
+        out_shape=out_struct((Z, Y, X), u.dtype, u, u_prev, c2),
         scratch_shapes=[
+            pltpu.VMEM(_ext_shape(Z, Y, X, R), u.dtype),
             pltpu.VMEM((2, R, Y, X), u.dtype),
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=pltpu.TPUCompilerParams(collective_id=1),
+        compiler_params=pltpu.CompilerParams(
+            collective_id=1, vmem_limit_bytes=VMEM_LIMIT_BYTES),
     )(u, u_prev, c2)
 
 
@@ -498,9 +527,14 @@ def fused_wave_step(
     plan_halo_slots` for the traced shapes; ``interpret=None`` resolves
     from the backend at call time.  ``z_extents`` (static per-rank tuple)
     enables asymmetric Z decomposition; ``halos``/``return_halos`` thread
-    the carried-halo state of the multi-step time loop.  Configurations the
-    compiled kernel does not cover (2-D, asymmetric, carried halos) always
-    route through the emulation — which XLA still compiles on TPU.
+    the carried-halo state of the multi-step time loop.
+
+    Off the interpreter, a single rank (no exchanging axis) runs the
+    slab-streamed Pallas stencil (:func:`~repro.kernels.stencil.kernel.
+    wave_step_pallas`); configurations the compiled fused kernel does not
+    cover (2-D, asymmetric, carried halos, shards over ``VMEM_LIMIT_BYTES``)
+    route through the emulation — which XLA still compiles on TPU.  Neither
+    Pallas path has a VJP: differentiate through ``interpret=True``.
     """
     from repro.core.compat import axis_size
 
@@ -534,15 +568,25 @@ def fused_wave_step(
     if plan.halo != RADIUS:
         raise ValueError(f"plan.halo={plan.halo} != stencil radius {RADIUS}")
 
-    # the compiled kernel keeps u/u_prev/c2/out + the halo landing windows
-    # wholly resident in VMEM; larger shards take the emulation, which XLA
-    # pipelines through HBM on TPU
-    item = jnp.dtype(u.dtype).itemsize
-    kernel_bytes = (4 * Z + 2 * RADIUS) * Y * X * item
-    needs_emulation = (ny > 1 or z_extents is not None
-                       or halos is not None or return_halos
-                       or kernel_bytes > default_planner().vmem_budget)
-    if resolve_interpret(interpret) or needs_emulation:
+    if resolve_interpret(interpret):
+        return fused_wave_step_interpret(
+            u, u_prev, c2dt2, zgroup, ygroup, plan=plan, dx=dx,
+            halos=halos, z_extents=z_extents, return_halos=return_halos)
+    if not plan.exchange_axes and z_extents is None and \
+            default_planner().stencil_fits(1, Y, X, u.dtype, radius=RADIUS,
+                                           budget=VMEM_LIMIT_BYTES):
+        # one rank holds the whole grid: nothing to exchange, and the
+        # streamed slab kernel holds a one-plane slab of this width
+        from .ops import wave_step
+        out = wave_step(u, u_prev, c2dt2, dx=dx, impl="pallas",
+                        interpret=False)
+        return (out, None) if return_halos else out
+    # the compiled fused kernel keeps u/u_prev/c2/out + the halo landing
+    # windows wholly resident in VMEM; larger shards take the emulation,
+    # which XLA pipelines through HBM on TPU
+    if (ny > 1 or z_extents is not None or halos is not None or return_halos
+            or fused_resident_bytes(Z, Y, X, u.dtype, RADIUS)
+            > VMEM_LIMIT_BYTES):
         return fused_wave_step_interpret(
             u, u_prev, c2dt2, zgroup, ygroup, plan=plan, dx=dx,
             halos=halos, z_extents=z_extents, return_halos=return_halos)

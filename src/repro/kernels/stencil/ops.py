@@ -1,8 +1,9 @@
 """jit'd public wrapper for the acoustic wave step.
 
 ``bz=None`` sizes the Z slab through the shared OverlapPlanner (the halo
-slab must double-buffer inside the VMEM budget — the StreamPool.plan_slots
-contract); ``interpret=None`` resolves from the backend at call time.
+slab must double-buffer inside the VMEM limit the kernel compiles with —
+the StreamPool.plan_slots contract); ``interpret=None`` resolves from the
+backend at call time.
 
 The resolution happens HERE, before the jit boundary, so the jit cache is
 keyed on the *resolved* flag rather than on ``None``: a cached trace can
@@ -18,7 +19,8 @@ from typing import Optional
 
 import jax
 
-from repro.kernels.plan import default_planner, resolve_interpret
+from repro.kernels.plan import (VMEM_LIMIT_BYTES, default_planner,
+                                resolve_interpret)
 from .fused import exchange_halos, fused_wave_step  # noqa: F401 - re-export
 from .kernel import wave_step_pallas
 from .ref import RADIUS
@@ -45,7 +47,8 @@ def wave_step(u, u_prev, c2dt2, *, dx: float = 1.0, impl: str = "ref",
         interpret = resolve_interpret(interpret)
         if bz is None:
             bz = default_planner().plan_stencil_bz(
-                u.shape[0], u.shape[1], u.shape[2], u.dtype, radius=RADIUS)
+                u.shape[0], u.shape[1], u.shape[2], u.dtype, radius=RADIUS,
+                budget=VMEM_LIMIT_BYTES)
     else:
         # the ref path ignores both knobs: normalize them out of the jit key
         # so explicit values cannot mint duplicate cache entries

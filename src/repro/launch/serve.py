@@ -5,6 +5,9 @@ controls: docs/SERVING.md "Overload & SLOs").
   PYTHONPATH=src python -m repro.launch.serve --arch glm4-9b --reduced \\
       --requests 6 --max-new 8 --prefill-chunk 16
 
+``--no-reduced`` serves the published widths (random weights, seed 0);
+``--max-len`` sizes the KV cache and the page arena.
+
 Passing any of --ttft-deadline-s / --total-deadline-s / --rate-per-s
 arms the SLO layer: deadline-aware admission, bounded queue with
 backpressure, load shedding, and staged degraded modes.  With deadlines
@@ -23,6 +26,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.models import schema as sch
 from repro.models.config import ParallelCtx
@@ -32,10 +36,16 @@ from repro.serve.engine import ServeEngine
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="glm4-9b", choices=configs.all_archs())
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="serve the reduced config (--no-reduced: the "
+                         "published widths)")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=8)
     ap.add_argument("--slots", type=int, default=3)
+    ap.add_argument("--max-len", type=int, default=96,
+                    help="KV-cache rows per slot (prompt + generated)")
+    ap.add_argument("--min-prompt", type=int, default=2)
     ap.add_argument("--max-prompt", type=int, default=24)
     ap.add_argument("--prefill-chunk", type=int, default=16,
                     help="prompt tokens per prefill device call "
@@ -75,19 +85,23 @@ def main(argv=None):
             max_queue=args.max_queue, queue_high=args.queue_high,
             queue_low=args.queue_low)
 
-    cfg = configs.get_reduced(args.arch)
+    enable_compile_cache()
+    cfg = configs.get_reduced(args.arch) if args.reduced \
+        else configs.get(args.arch)
     mesh = make_smoke_mesh(len(jax.devices()))
     ctx = ParallelCtx.from_mesh(mesh, remat=False, inference=True)
     params = sch.init_params(cfg, jax.random.PRNGKey(0))
 
-    eng = ServeEngine(cfg, mesh, ctx, params, slots=args.slots, max_len=96,
+    eng = ServeEngine(cfg, mesh, ctx, params, slots=args.slots,
+                      max_len=args.max_len,
                       prefill_chunk=args.prefill_chunk,
                       page_tokens=args.page_tokens,
                       temperature=args.temperature, top_k=args.top_k,
                       high_watermark=args.high_watermark, slo=slo)
     rng = np.random.RandomState(0)
     reqs = [eng.submit(rng.randint(0, cfg.vocab_size,
-                                   size=rng.randint(2, args.max_prompt)),
+                                   size=rng.randint(args.min_prompt,
+                                                    args.max_prompt + 1)),
                        max_new=args.max_new)
             for _ in range(args.requests)]
     t0 = time.time()
@@ -110,6 +124,7 @@ def main(argv=None):
     else:
         assert done == len(reqs)
     print("serve driver done")
+    return eng, reqs
 
 
 if __name__ == "__main__":

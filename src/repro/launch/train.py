@@ -30,6 +30,7 @@ import numpy as np
 from repro import configs
 from repro.core.runtime import DiompRuntime
 from repro.data.pipeline import Prefetcher, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import api as model_api
 from repro.models import schema as sch
@@ -69,6 +70,7 @@ def main(argv=None):
     ap.add_argument("--max-restarts", type=int, default=1)
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     fault_plan = None
     if args.chaos_seed is not None:
         from repro.core.faults import FaultPlan

@@ -67,11 +67,23 @@ def supports_long_context(cfg: ModelConfig) -> bool:
 
 def _batch_axes(mesh: Mesh, B: int,
                 dp_axes: Tuple[str, ...] = ("pod", "data")) -> Tuple[str, ...]:
+    """DP axes the batch dim shards over: none when ``B`` does not divide
+    over them, or when they hold one device in all (sharding over size-1
+    axes is replication, and would only mark the batch varying over axes
+    that the rest of the step treats as invariant)."""
     axes = tuple(a for a in dp_axes if a in mesh.shape)
     n = 1
     for a in axes:
         n *= mesh.shape[a]
-    return axes if (axes and B % n == 0) else ()
+    return axes if (axes and n > 1 and B % n == 0) else ()
+
+
+def _sharding_axis(mesh: Mesh, name: str) -> Optional[str]:
+    """``name`` if that mesh axis splits anything, else None: a size-1 axis
+    shards nothing, and naming it would mark the value varying over an
+    axis the collectives treat as invariant (see
+    :meth:`repro.distributed.sharding.ShardingRules.lookup`)."""
+    return name if mesh.shape.get(name, 1) > 1 else None
 
 
 def batch_structs(cfg: ModelConfig, mesh: Mesh, B: int, S: int,
@@ -110,7 +122,7 @@ def cache_structs(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx, B: int,
     """
     ba = _batch_axes(mesh, B)
     bspec = ba if ba else None
-    sspec = "data" if seq_sharded else None
+    sspec = _sharding_axis(mesh, "data") if seq_sharded else None
     S_glob = S
     kd = cfg.first_k_dense if cfg.moe else 0
     L = cfg.num_layers - kd
@@ -122,7 +134,8 @@ def cache_structs(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx, B: int,
         kv_model = sch.kv_sharded(cfg) or (
             sch.head_parallel(cfg) and ctx.tp > 1)
         KH_glob = KH_loc * ctx.tp if kv_model else cfg.kv_heads
-        spec = P(None, bspec, sspec, "model" if kv_model else None, None)
+        spec = P(None, bspec, sspec,
+                 _sharding_axis(mesh, "model") if kv_model else None, None)
         return (jax.ShapeDtypeStruct((L, B, S_glob, KH_glob, cfg.head_dim),
                                      dtype), spec)
 

@@ -63,7 +63,19 @@ from .kvcache import PagedKVAllocator, Request
 from .slo import AdmissionController, AdmissionDecision, SLOPolicy, percentiles
 from .step import build_chunk_prefill_step, build_decode_step
 
-__all__ = ["ServeEngine", "GenRequest"]
+__all__ = ["ServeEngine", "GenRequest", "kv_arena_bytes"]
+
+
+def kv_arena_bytes(slots: int, max_len: int, page_tokens: int,
+                   kv_bytes_per_token: int) -> int:
+    """Per-rank KV-page arena for ``slots`` requests of up to ``max_len``
+    rows: each slot's largest page table (``max_len`` rows plus the growth
+    page ``admit`` reserves), in the power-of-two blocks the buddy
+    allocator hands out, twice over — a full engine then sits at half the
+    arena, under the default preemption watermarks."""
+    pages = -(-max_len // page_tokens) + 1
+    block = 1 << max(page_tokens * kv_bytes_per_token - 1, 1).bit_length()
+    return 2 * slots * pages * block
 
 
 @dataclasses.dataclass(eq=False)       # identity semantics: requests are
@@ -84,6 +96,7 @@ class GenRequest:                      # scheduled objects, not values
     finish_t: Optional[float] = None
     admit_step: int = -1
     finish_step: int = -1
+    first_logits: Optional[np.ndarray] = None  # (V,) row that chose out[0]
     prefill_steps: int = 0      # chunk-prefill device calls for this request
     decode_steps: int = 0       # decode steps this request participated in
     preemptions: int = 0
@@ -152,19 +165,21 @@ class ServeEngine:
         self.seed = int(seed)
         self.high_watermark = float(high_watermark)
         self.low_watermark = float(low_watermark)
+        kv_bpt = 2 * 2 * max(cfg.kv_heads, 1) * max(cfg.head_dim, 1) \
+            * cfg.num_layers
         # the engine runs on a DiompContext: the KV-page arena is its PGAS
         # memory, the world group its communicator domain.  A caller-provided
         # `memory` (legacy) still wins for the arena.
         if context is None:
-            context = DiompContext(mesh=mesh, segment_bytes=1 << 26,
-                                   allocator="buddy")
+            context = DiompContext(
+                mesh=mesh, allocator="buddy",
+                segment_bytes=kv_arena_bytes(slots, max_len, page_tokens,
+                                             max(kv_bpt, 64)))
         self.dctx = context
         self.memory = memory or context.memory
         self._group = context.groups.get(
             "world", DiompGroup(tuple(mesh.axis_names), name="world"))
         self._comm = self.dctx.communicator(self._group)
-        kv_bpt = 2 * 2 * max(cfg.kv_heads, 1) * max(cfg.head_dim, 1) \
-            * cfg.num_layers
         self.alloc = PagedKVAllocator(
             self.memory, self._group,
             page_tokens=page_tokens, kv_bytes_per_token=max(kv_bpt, 64))
@@ -725,6 +740,8 @@ class ServeEngine:
         return int(req._rng.choice(keep, p=p))
 
     def _commit(self, slot: int, req: GenRequest, row: np.ndarray) -> None:
+        if not req.out:
+            req.first_logits = row
         req.out.append(self._sample(req, row))
         now = self.clock()
         if req.first_token_t is None:

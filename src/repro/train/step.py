@@ -34,14 +34,14 @@ Bucketing + backward overlap (the §Perf reduction path):
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.compat import HAS_VMA, shard_map
+from repro.core.compat import shard_map
 
 from repro.core import ompccl
 from repro.core.context import default_context
@@ -171,18 +171,6 @@ def reduce_gradients(grads: Dict[str, jax.Array], cfg: ModelConfig,
     return out, new_errors
 
 
-def _flat_dp_reduce(grads: Dict[str, jax.Array], pspecs: dict,
-                    dp_axes: Tuple[str, ...], dp: int):
-    """DP mean-reduction per parameter over the axes its sharding does not
-    already consume — the reduction a vma-aware AD emits implicitly."""
-    out = {}
-    for name, g in grads.items():
-        need = _unreduced_dp_axes(pspecs[name], dp_axes)
-        g = g.astype(F32) / dp
-        out[name] = lax.psum(g, need) if need else g
-    return out
-
-
 def build_train_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
                      optimizer: Optimizer, *, optimizer_name: str = "adamw",
                      clip_norm: float = 1.0, donate: bool = True,
@@ -215,7 +203,9 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
     pspecs = sch.partition_specs(cfg, mesh, rules)
     ospecs = opt_state_specs(cfg, mesh, optimizer_name, rules)
     dp_axes = ctx.dp_group.axes
-    all_axes = tuple(mesh.axis_names)
+    # axes a value can vary over: a size-1 axis splits nothing, and the
+    # partition specs leave it out (sharding.ShardingRules.lookup)
+    all_axes = tuple(a for a in mesh.axis_names if mesh.shape[a] > 1)
     mesh_sizes = dict(mesh.shape)
     if not global_batch:  # default: assume a dp-divisible batch
         global_batch = ctx.dp
@@ -272,6 +262,14 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
                 return {n: ompccl.ensure_varying(v, leaf_axes(n))
                         for n, v in g.items()}
 
+            def bucket_axes(b):
+                # a bucket's reduce axes plus its members' own axes: what
+                # the packed bucket (pack_buckets) varies over
+                axes = set(b.axes)
+                for sl in b.slices:
+                    axes.update(leaf_axes(sl.name))
+                return tuple(a for a in all_axes if a in axes)
+
             if overlap:
                 # resolved at trace time like every other collective site
                 dctx = default_context()
@@ -295,7 +293,7 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
                         piece = comms[b.key].reducescatter(mb_bufs[b.key],
                                                            axis=0)
                         sh[b.key] = ompccl.ensure_varying(
-                            sh_acc[b.key] + piece, all_axes)
+                            sh_acc[b.key] + piece, bucket_axes(b))
                     aux_acc = tuple(
                         ompccl.ensure_varying(a + x, all_axes)
                         for a, x in zip(aux_acc, aux))
@@ -306,7 +304,8 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
                                  for n in plan.local})
                 zero_sh = {
                     b.key: ompccl.ensure_varying(
-                        jnp.zeros((b.shard_size(mesh_sizes),), F32), all_axes)
+                        jnp.zeros((b.shard_size(mesh_sizes),), F32),
+                        bucket_axes(b))
                     for b in plan.buckets}
                 loss0 = ompccl.ensure_varying(jnp.zeros((), F32), all_axes)
                 aux0 = tuple(ompccl.ensure_varying(jnp.zeros((), F32),
@@ -362,11 +361,6 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
                 else:
                     grads, _ = reduce_gradients(grads, cfg, ctx,
                                                 pspecs=pspecs)
-        elif dp_axes and not HAS_VMA:
-            # pre-vma jax inserts no automatic pvary-transpose psums under
-            # shard_map, so the "implicit" baseline must still reduce on the
-            # wire: same flat psum the vma transpose would have emitted
-            grads = _flat_dp_reduce(grads, pspecs, dp_axes, ctx.dp)
         else:
             grads = jax.tree.map(lambda g: g.astype(F32) / ctx.dp, grads)
 

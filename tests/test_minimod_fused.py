@@ -47,7 +47,7 @@ def _reference(u, up, c2, steps, dx=1.0):
 
 
 def _run_step(Z, Y, X, nz, ny=1, z_extents=None, dtype=np.float32,
-              c2=0.1, ctx=None):
+              c2=0.1, ctx=None, interpret=None, check_vma=True):
     """One fused step under shard_map; returns (got, want) logical grids."""
     mesh = make_mesh((nz, ny), ("z", "y"), axis_types="auto")
     ext = z_extents or (Z // nz,) * nz
@@ -57,11 +57,11 @@ def _run_step(Z, Y, X, nz, ny=1, z_extents=None, dtype=np.float32,
 
     def step(a, b):
         return fused_wave_step(a, b, c2, ZG, YG if ny > 1 else None,
-                               z_extents=z_extents)
+                               z_extents=z_extents, interpret=interpret)
 
     f = jax.jit(shard_map(step, mesh=mesh,
                           in_specs=(P("z", "y"), P("z", "y")),
-                          out_specs=P("z", "y")))
+                          out_specs=P("z", "y"), check_vma=check_vma))
     with use_default(ctx or DiompContext(mesh=mesh)):
         got = unpad_shards(np.asarray(f(u_in, up_in)), ext)
     want = _reference(u, up, c2, 1)
@@ -82,6 +82,22 @@ def _run_step(Z, Y, X, nz, ny=1, z_extents=None, dtype=np.float32,
 ])
 def test_fused_step_matches_reference(Z, Y, X, nz, ny, ext):
     got, want = _run_step(Z, Y, X, nz, ny, z_extents=ext)
+    np.testing.assert_allclose(got, want, atol=3e-6)
+
+
+@pytest.mark.parametrize("Z,Y,X,nz", [
+    (64, 12, 10, 4),     # the fused kernel: puts under the interior planes
+    (32, 12, 10, 4),     # the fused kernel, no interior: all boundary
+    (16, 8, 8, 1),       # one rank: the slab-streamed stencil kernel
+])
+def test_tpu_kernels_match_reference_in_tpu_interpreter(Z, Y, X, nz):
+    """The compiled path's kernel bodies (remote copies and semaphores
+    simulated across the CPU devices by Pallas' TPU interpreter, whose own
+    ops carry no vma types)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    with pltpu.force_tpu_interpret_mode():
+        got, want = _run_step(Z, Y, X, nz, interpret=False, check_vma=False)
     np.testing.assert_allclose(got, want, atol=3e-6)
 
 

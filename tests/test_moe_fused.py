@@ -154,19 +154,20 @@ def _dispatch_case(ndev, E, t_loc, d, f, k=2, skew=2.0):
     return toks, router, (wg, wu, wd), plan, loads, want
 
 
-def _run_dispatch(mesh, impl, plan, toks, router, weights, k=2):
+def _run_dispatch(mesh, impl, plan, toks, router, weights, k=2,
+                  interpret=None, check_vma=True):
     def f(tk, rt, g, u, dn):
         w, e = route_topk(tk, rt, k)
         with default_context().dispatch_stats.collect() as ds:
             out = moe_dispatch(tk, e, w, g, u, dn, GROUP,
-                               impl=impl, plan=plan)
+                               impl=impl, plan=plan, interpret=interpret)
         return out, ds["moe_dropped"].reshape(1)
 
     fn = jax.jit(shard_map(
         f, mesh=mesh,
         in_specs=(P("x", None), P(None, None), P("x", None, None),
                   P("x", None, None), P("x", None, None)),
-        out_specs=(P("x", None), P("x"))))
+        out_specs=(P("x", None), P("x")), check_vma=check_vma))
     out, dropped = fn(toks, router, *weights)
     return np.asarray(out), float(np.asarray(dropped).sum())
 
@@ -183,6 +184,24 @@ def test_fused_and_host_match_oracle_under_imbalance():
     assert d_fused == 0.0 and d_host == 0.0
     np.testing.assert_array_equal(fused, want)
     np.testing.assert_array_equal(host, want)
+
+
+@pytest.mark.parametrize("ndev", [2, 4])
+def test_tpu_kernel_matches_oracle_in_tpu_interpreter(ndev):
+    """The compiled kernel's body, its remote copies and semaphores
+    simulated across the CPU devices by Pallas' TPU interpreter (whose own
+    ops carry no vma types)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    mesh = make_mesh((ndev,), ("x",), axis_types="auto")
+    toks, router, weights, plan, _, want = _dispatch_case(
+        ndev, E=2 * ndev, t_loc=8, d=16, f=24)
+    with pltpu.force_tpu_interpret_mode():
+        out, dropped = _run_dispatch(mesh, "fused", plan, toks, router,
+                                     weights, interpret=False,
+                                     check_vma=False)
+    assert dropped == 0.0
+    np.testing.assert_allclose(out, want, rtol=1e-5, atol=1e-5)
 
 
 def test_undersized_plan_records_drops():

@@ -55,6 +55,40 @@ def _ring_fn(mesh, impl, **kw):
 
 
 # ---------------------------------------------------------------------------
+# the TPU kernel body, run by Pallas' TPU interpreter (remote copies and
+# semaphores simulated across the CPU devices) against the oracle
+# ---------------------------------------------------------------------------
+
+TPU_CASES = [
+    ("n4_causal_gqa_d80", 4, dict(), dict(tq=8, D=80, DV=80, B=1)),
+    ("n4_bidi_mqa_dv_ne_d", 4, dict(causal=False), dict(tq=8, KH=1, DV=64)),
+    ("n2_causal_valid_len", 2, dict(valid_len=13), dict(tq=8, KH=4)),
+]
+
+
+@pytest.mark.parametrize("name,n,kw,ckw", TPU_CASES,
+                         ids=[c[0] for c in TPU_CASES])
+def test_tpu_kernel_matches_oracle_in_tpu_interpreter(name, n, kw, ckw):
+    from jax.experimental.pallas import tpu as pltpu
+
+    q, k, v = _case(n, **ckw)
+    causal = kw.get("causal", True)
+    want = np.asarray(ring_attention_ref(q, k, v, n=n, causal=causal,
+                                         valid_len=kw.get("valid_len")))
+    spec = P(None, "x")
+    # the interpreter's own ops carry no vma types: check_vma off here (the
+    # compiled kernel runs vma-checked; tests/test_tpu_compile.py)
+    fn = jax.jit(shard_map(
+        lambda q, k, v: ring_attention(q, k, v, GROUP, impl="fused",
+                                       interpret=False, **kw),
+        mesh=_mesh(n), in_specs=(spec,) * 3, out_specs=spec,
+        check_vma=False))
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(fn(q, k, v))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
 # forward: fused == host == oracle, bitwise
 # ---------------------------------------------------------------------------
 
@@ -179,7 +213,7 @@ def test_chunked_prefill_traced_offset_bitwise(impl):
 
     fn = jax.jit(shard_map(
         f, mesh=mesh, in_specs=(P(), P(None, "x"), P(None, "x"), P()),
-        out_specs=P(), check_rep=False))
+        out_specs=P(), check_vma=False))
     got = np.asarray(fn(q, k, v, jnp.asarray(p0, jnp.int32)))
     want = np.asarray(ring_attention_ref(q, k, v, n=n, causal=True,
                                          q_offset=p0, valid_len=p0 + tq,
@@ -306,7 +340,7 @@ def test_attention_block_seq_parallel_ring_matches_allgather():
             return out
 
         return jax.jit(shard_map(f, mesh=mesh, in_specs=(P(),),
-                                 out_specs=P(), check_rep=False))(x)
+                                 out_specs=P(), check_vma=False))(x)
 
     a, r = run("allgather"), run("ring")
     np.testing.assert_allclose(np.asarray(a), np.asarray(r),

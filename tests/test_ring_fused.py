@@ -29,7 +29,7 @@ RNG = np.random.RandomState(0)
 GROUP = DiompGroup(("x",), name="ring")
 
 
-def _run(T, K, N, ndev, dtype=np.float32, **kwargs):
+def _run(T, K, N, ndev, dtype=np.float32, check_vma=True, **kwargs):
     """Fused matmul + reference on an ndev ring; returns (got, want, full)."""
     mesh = make_mesh((ndev,), ("x",), axis_types="auto")
     A = RNG.randn(T, K).astype(dtype)
@@ -37,7 +37,7 @@ def _run(T, K, N, ndev, dtype=np.float32, **kwargs):
     f = jax.jit(shard_map(
         lambda a, b: ring_allgather_matmul(a, b, GROUP, **kwargs),
         mesh=mesh, in_specs=(P("x", None), P(None, "x")),
-        out_specs=P(None, "x")))
+        out_specs=P(None, "x"), check_vma=check_vma))
     r = jax.jit(shard_map(
         lambda a, b: ring_allgather_matmul_ref(a, b, GROUP),
         mesh=mesh, in_specs=(P("x", None), P(None, "x")),
@@ -162,6 +162,27 @@ def test_fused_matches_reference(T, K, N, ndev):
     scale = np.abs(A @ B).max()
     assert np.abs(got - want).max() / scale < 1e-4
     assert np.abs(got - A @ B).max() / scale < 1e-4
+
+
+@pytest.mark.parametrize("T,K,N,ndev,direction", [
+    (64, 64, 64, 8, "bidi"),
+    (8, 17, 8, 4, "bidi"),      # ragged K, tiny stripes
+    (30, 64, 36, 2, "bidi"),    # one exchange step
+    (24, 32, 16, 4, "cw"),
+])
+def test_tpu_kernel_matches_reference_in_tpu_interpreter(T, K, N, ndev,
+                                                         direction):
+    """The compiled kernel's body, its remote copies and semaphores
+    simulated across the CPU devices by Pallas' TPU interpreter (whose own
+    ops carry no vma types)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    plan = RingPlan(n=ndev, direction=direction, slots=2)
+    with pltpu.force_tpu_interpret_mode():
+        got, want, (A, B) = _run(T, K, N, ndev, impl="fused", plan=plan,
+                                 interpret=False, check_vma=False)
+    scale = np.abs(A @ B).max()
+    assert np.abs(got - want).max() / scale < 1e-4
 
 
 def test_fused_bf16():

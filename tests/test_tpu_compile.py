@@ -1,0 +1,185 @@
+"""Compile the main path's Pallas kernels for a described TPU v5e.
+
+No chip is needed: the TPU compiler compiles for a ``v5e:2x2`` topology
+that is described, not attached, and refuses here what the chip would
+refuse (unaligned slices, more VMEM than a kernel may use, a program that
+does not fit).  Nothing runs, so these tests say nothing about results or
+times; ``chip_smoke.py`` is the run on the chip.
+
+The topology is described inside a module fixture (never at import time):
+only one process at a time may load the TPU library, so this file's tests
+stay together in one file and compile in the test's own process.  The
+persistent compilation cache is off around them — an entry written for a
+described chip cannot be read back without one.
+
+The fused kernels compile under ``shard_map``'s vma checking, as the
+program runs them.
+"""
+
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
+
+from repro.core.groups import DiompGroup
+from repro.kernels.flash_attention.kernel import flash_attention_pallas
+from repro.kernels.moe_dispatch.fused import fused_moe_dispatch_tpu
+from repro.kernels.plan import OverlapPlanner, RingPlan, VMEM_LIMIT_BYTES
+from repro.kernels.ring_attention.fused import (
+    fused_ring_attention_resident_bytes, fused_ring_attention_tpu)
+from repro.kernels.ring_matmul.fused import (fused_ring_allgather_matmul_tpu,
+                                             fused_ring_resident_bytes)
+from repro.kernels.ring_matmul.kernel import matmul_pallas
+from repro.kernels.stencil.fused import fused_wave_step_tpu
+from repro.kernels.stencil.ops import wave_step
+
+BF16, F32 = jnp.bfloat16, jnp.float32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here
+        jax.config.update("jax_enable_compilation_cache", was)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def ring4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("x",))
+
+
+def _compile(f, *structs):
+    compiled = jax.jit(f).lower(*structs).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _struct(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+# -- one chip: the kernels at real widths -------------------------------------
+
+def test_flash_attention_stablelm_widths(one_chip):
+    """stablelm-3b: 32 heads x 80, 2048 tokens, bf16."""
+    q = _struct((1, 32, 2048, 80), BF16, one_chip)
+    _compile(lambda q, k, v: flash_attention_pallas(
+        q, k, v, causal=True, block_q=512, block_k=512), q, q, q)
+
+
+def test_matmul_stablelm_mlp(one_chip):
+    """4096 tokens x d_model 2560 x d_ff 6912, bf16."""
+    _compile(matmul_pallas, _struct((4096, 2560), BF16, one_chip),
+             _struct((2560, 6912), BF16, one_chip))
+
+
+def test_wave_step_full_grid(one_chip):
+    """Minimod's one-chip grid: 512³ f32, the slab height from the planner
+    against the kernel's VMEM limit."""
+    g = _struct((512, 512, 512), F32, one_chip)
+    compiled = _compile(lambda u, up: wave_step(
+        u, up, 0.1, impl="pallas", interpret=False), g, g)
+    assert compiled.memory_analysis().temp_size_in_bytes < 16e9
+
+
+# -- four chips: the fused one-sided kernels ----------------------------------
+
+def test_fused_ring_matmul_stablelm_mlp(ring4):
+    """All-gather matmul at the stablelm MLP: 4096 tokens, K 2560, N 6912
+    split over 4 — the size chip_smoke.py --chips 4 runs."""
+    T, K, N = 4096, 2560, 6912
+    plan = RingPlan(n=4)
+    assert fused_ring_resident_bytes(T // 4, K, N // 4, BF16, plan) \
+        <= VMEM_LIMIT_BYTES
+
+    def f(x, w):
+        return jax.shard_map(
+            lambda a, b: fused_ring_allgather_matmul_tpu(a, b, axis="x",
+                                                         plan=plan),
+            mesh=ring4, in_specs=(P("x"), P(None, "x")),
+            out_specs=P(None, "x"))(x, w)
+
+    _compile(f, _struct((T, K), BF16, NamedSharding(ring4, P("x"))),
+             _struct((K, N), BF16, NamedSharding(ring4, P(None, "x"))))
+
+
+def test_fused_stencil_step(ring4):
+    """The fused halo-overlapped step on a shard that stays VMEM-resident
+    (Z 4·64 x 128 x 128 f32)."""
+    plan = OverlapPlanner().plan_halo_slots(64, 128, 128, F32, 4)
+    mesh = Mesh(np.array(ring4.devices).reshape(4, 1), ("z", "y"))
+    spec = NamedSharding(mesh, P("z", "y"))
+
+    def f(u, up):
+        return jax.shard_map(
+            lambda a, b: fused_wave_step_tpu(a, b, 0.1, axis="z", plan=plan),
+            mesh=mesh, in_specs=(P("z", "y"), P("z", "y")),
+            out_specs=P("z", "y"))(u, up)
+
+    g = _struct((256, 128, 128), F32, spec)
+    _compile(f, g, g)
+
+
+def test_fused_moe_dispatch(ring4):
+    """Dropless expert-parallel dispatch: 8 experts over 4 chips, top-2,
+    128 tokens per chip, d 256, f 512."""
+    t_loc, d, f, E, k = 128, 256, 512, 8, 2
+    plan = OverlapPlanner().plan_alltoall(t_loc, d, k, E, 4, BF16)
+    group = DiompGroup(("x",), name="ep")
+    tok = NamedSharding(ring4, P("x"))
+
+    def fn(toks, top_e, top_w, wg, wu, wd):
+        def body(*a):
+            out, _ = fused_moe_dispatch_tpu(*a, group, plan=plan)
+            return out
+        return jax.shard_map(body, mesh=ring4, in_specs=(P("x"),) * 6,
+                             out_specs=P("x"))(
+            toks, top_e, top_w, wg, wu, wd)
+
+    _compile(fn, _struct((4 * t_loc, d), BF16, tok),
+             _struct((4 * t_loc, k), jnp.int32, tok),
+             _struct((4 * t_loc, k), F32, tok),
+             _struct((E, d, f), BF16, tok), _struct((E, d, f), BF16, tok),
+             _struct((E, f, d), BF16, tok))
+
+
+def test_fused_ring_attention_stablelm_heads(ring4):
+    """Causal sequence-parallel attention at stablelm-3b's heads (32 x 80,
+    head dim padded to 128 lanes in the kernel), 4·256 tokens, bf16."""
+    T, H, D = 4 * 256, 32, 80
+    plan = OverlapPlanner().plan_ring_attention(1, T // 4, T // 4, H, H, D,
+                                                D, BF16, 4, causal=True)
+    assert fused_ring_attention_resident_bytes(plan, BF16) <= VMEM_LIMIT_BYTES
+
+    def f(q, k, v):
+        return jax.shard_map(
+            lambda a, b, c: fused_ring_attention_tpu(a, b, c, axis="x",
+                                                     plan=plan),
+            mesh=ring4, in_specs=(P(None, "x"),) * 3,
+            out_specs=P(None, "x"))(q, k, v)
+
+    g = _struct((1, T, H, D), BF16, NamedSharding(ring4, P(None, "x")))
+    _compile(f, g, g, g)
