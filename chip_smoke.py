@@ -167,11 +167,24 @@ def serve_phase(clock, *, arch="stablelm-3b", reduced=False, slots=4,
 
     ref_fn = jax.jit(shard_map(forward, mesh=mesh, in_specs=(pspecs, P()),
                                out_specs=P()))
-    req = reqs[0]
-    want = np.asarray(ref_fn(params, jnp.asarray(req.prompt[None])))[0]
-    got = np.asarray(req.first_logits, np.float32)
-    err = float(np.abs(got - want).max() / np.abs(want).max())
     stats = eng.latency_stats()
+    # serve the first prompt once more, keeping its last prefill chunk's
+    # logits: the row that chooses the first token
+    req = reqs[0]
+    chunk_step, rows = eng.chunk_step, []
+
+    def keep(*a):
+        out = chunk_step(*a)
+        rows.append(out[0])
+        return out
+
+    eng.chunk_step = keep
+    eng.submit(req.prompt, max_new=1)
+    eng.run()
+    eng.chunk_step = chunk_step
+    want = np.asarray(ref_fn(params, jnp.asarray(req.prompt[None])))[0]
+    got = np.asarray(rows[-1], np.float32)[0, 0]
+    err = float(np.abs(got - want).max() / np.abs(want).max())
     log(f"[serve] {cfg.name}: {len(reqs)} requests, prompts "
         f"{min(len(r.prompt) for r in reqs)}-"
         f"{max(len(r.prompt) for r in reqs)} tokens, max_new={max_new}, "

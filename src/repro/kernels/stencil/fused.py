@@ -487,6 +487,7 @@ def fused_wave_step_tpu(u, u_prev, c2dt2, *, axis: str, plan: HaloPlan,
     c2 = jnp.broadcast_to(jnp.asarray(c2dt2, u.dtype), u.shape)
     return pl.pallas_call(
         functools.partial(_fused_stencil_kernel, axis=axis, plan=plan, dx=dx),
+        name="stencil_fused_step",
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
             pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),

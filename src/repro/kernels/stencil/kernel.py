@@ -99,6 +99,7 @@ def wave_step_pallas(u, u_prev, c2dt2, *, dx: float = 1.0, bz: int = 8,
 
     out = pl.pallas_call(
         functools.partial(_stencil_kernel, bz=bz, Y=Y, X=X, dx=dx),
+        name="stencil_step",
         grid=(Zp // bz,),
         in_specs=[
             pl.BlockSpec(memory_space=pl.ANY),              # padded u in HBM

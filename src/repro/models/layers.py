@@ -465,10 +465,11 @@ def attention_block(
 
     new_cache = cache
     if decode:
-        new_cache = _update_cache(
-            cache, k, v,
-            ctx.fsdp_group if cache.seq_sharded else None,
-        )
+        with jax.named_scope("kv_write"):
+            new_cache = _update_cache(
+                cache, k, v,
+                ctx.fsdp_group if cache.seq_sharded else None,
+            )
         if cache.seq_sharded:
             attn = cp_decode_attention(q, new_cache, ctx.fsdp_group,
                                        scale=hd ** -0.5)
@@ -487,13 +488,14 @@ def attention_block(
         assert not cache.seq_sharded, \
             "chunked prefill does not support a context-sharded cache"
         p0 = cache.pos
-        new_cache = KVCache(
-            lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype),
-                                     (0, p0, 0, 0)),
-            lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype),
-                                     (0, p0, 0, 0)),
-            p0 + T, seq_sharded=False,
-        )
+        with jax.named_scope("kv_write"):
+            new_cache = KVCache(
+                lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype),
+                                         (0, p0, 0, 0)),
+                lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype),
+                                         (0, p0, 0, 0)),
+                p0 + T, seq_sharded=False,
+            )
         s_all = new_cache.k.shape[1]
         if ring_attn and s_all % ctx.tp == 0:
             # sequence-parallel chunked prefill: the cache is replicated

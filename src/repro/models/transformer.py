@@ -52,24 +52,26 @@ def _layer_body(x, lp, cfg: ModelConfig, ctx: ParallelCtx, *,
                 cache=None, chunked: bool = False):
     """One decoder block: (attn + residual) then (ffn + residual)."""
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, plus_one=(cfg.family == "vlm"))
-    if mla:
-        attn, new_cache = mla_block(h, lp, cfg, ctx, positions=positions,
-                                    cache=cache, chunked=chunked)
-    else:
-        attn, new_cache = attention_block(
-            h, lp, cfg, ctx, positions=positions, prefix_len=prefix_len,
-            cache=cache, causal=cfg.causal, chunked=chunked)
+    with jax.named_scope("attention"):
+        if mla:
+            attn, new_cache = mla_block(h, lp, cfg, ctx, positions=positions,
+                                        cache=cache, chunked=chunked)
+        else:
+            attn, new_cache = attention_block(
+                h, lp, cfg, ctx, positions=positions, prefix_len=prefix_len,
+                cache=cache, causal=cfg.causal, chunked=chunked)
     x = x + attn
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, plus_one=(cfg.family == "vlm"))
-    if moe:
-        ffn = moe_block(h, lp, cfg, ctx)
-        # deepseek keeps no separate dense FFN on MoE layers (shared experts
-        # are inside moe_block)
-    elif cfg.family == "audio":
-        ffn = gelu_mlp_block(h, lp, ctx)
-    else:
-        act = "gelu" if cfg.family == "vlm" else "silu"
-        ffn = mlp_block(h, lp, ctx, act=act)
+    with jax.named_scope("mlp"):
+        if moe:
+            ffn = moe_block(h, lp, cfg, ctx)
+            # deepseek keeps no separate dense FFN on MoE layers (shared
+            # experts are inside moe_block)
+        elif cfg.family == "audio":
+            ffn = gelu_mlp_block(h, lp, ctx)
+        else:
+            act = "gelu" if cfg.family == "vlm" else "silu"
+            ffn = mlp_block(h, lp, ctx, act=act)
     return x + ffn, new_cache
 
 
@@ -267,6 +269,13 @@ def _lm_head(params, cfg):
     return params["lm_head"]
 
 
+def _logits(h, params, cfg):
+    """The output head in float32: hidden (..., d) -> logits (..., V)."""
+    with jax.named_scope("lm_head"):
+        return jnp.dot(h.astype(jnp.float32),
+                       _lm_head(params, cfg).astype(jnp.float32))
+
+
 def transformer_loss(params, batch, cfg: ModelConfig, ctx: ParallelCtx):
     """Next-token CE (LM) or masked-frame CE (audio).  Scalar f32 loss."""
     if cfg.family == "audio":
@@ -308,9 +317,7 @@ def transformer_prefill(params, tokens, cfg, ctx, cache, *,
     h, cache = transformer_forward(params, tokens, cfg, ctx, cache=cache,
                                    prefix_embeds=prefix_embeds,
                                    seq_sharded=seq_sharded)
-    logits = jnp.dot(h[:, -1:].astype(jnp.float32),
-                     _lm_head(params, cfg).astype(jnp.float32))
-    return logits, cache
+    return _logits(h[:, -1:], params, cfg), cache
 
 
 def transformer_chunk_prefill(params, tokens, cfg, ctx, cache, rlen, *,
@@ -334,8 +341,7 @@ def transformer_chunk_prefill(params, tokens, cfg, ctx, cache, rlen, *,
     h, cache = transformer_forward(params, tokens, cfg, ctx, cache=cache,
                                    positions=positions, chunked=True)
     last = lax.dynamic_slice_in_dim(h, jnp.maximum(rlen - 1, 0), 1, axis=1)
-    logits = jnp.dot(last.astype(jnp.float32),
-                     _lm_head(params, cfg).astype(jnp.float32))
+    logits = _logits(last, params, cfg)
     # the layer scan advanced pos by the full (possibly padded) chunk width;
     # the true advance is the real token count
     cache["pos"] = p0 + rlen
@@ -355,6 +361,4 @@ def transformer_decode(params, tokens, cfg, ctx, cache, *,
     h, cache = transformer_forward(
         params, tokens, cfg, ctx, cache=cache,
         positions=positions, seq_sharded=seq_sharded)
-    logits = jnp.dot(h.astype(jnp.float32),
-                     _lm_head(params, cfg).astype(jnp.float32))
-    return logits, cache
+    return _logits(h, params, cfg), cache

@@ -57,6 +57,7 @@ from repro.core.groups import DiompGroup
 from repro.core.pgas import GlobalMemory
 from repro.core.resilience import CircuitBreaker
 from repro.core.rma import RMAError
+from repro.core.spans import Recorder, recorder
 from repro.models import api as model_api
 from repro.models.config import ModelConfig, ParallelCtx
 from .kvcache import PagedKVAllocator, Request
@@ -96,7 +97,6 @@ class GenRequest:                      # scheduled objects, not values
     finish_t: Optional[float] = None
     admit_step: int = -1
     finish_step: int = -1
-    first_logits: Optional[np.ndarray] = None  # (V,) row that chose out[0]
     prefill_steps: int = 0      # chunk-prefill device calls for this request
     decode_steps: int = 0       # decode steps this request participated in
     preemptions: int = 0
@@ -150,7 +150,8 @@ class ServeEngine:
                  context: Optional[DiompContext] = None,
                  slo: Optional[SLOPolicy] = None,
                  clock=None,
-                 breaker: Optional[CircuitBreaker] = None):
+                 breaker: Optional[CircuitBreaker] = None,
+                 spans: Optional[Recorder] = None):
         if cfg.family not in model_api.TRANSFORMER_FAMILIES \
                 or not model_api.has_decode(cfg):
             raise ValueError(
@@ -236,6 +237,9 @@ class ServeEngine:
         # immediately, probe again after the cooldown
         self.breaker = breaker if breaker is not None else CircuitBreaker(
             failure_threshold=1, cooldown_s=0.5, clock=self.clock)
+        # phase spans (docs/SERVING.md "measurement"): always on the
+        # perf_counter clock, never the injectable one
+        self.spans = spans if spans is not None else recorder()
 
     # -- API --------------------------------------------------------------
     def submit(self, prompt, max_new: int = 32, *, priority: int = 0,
@@ -311,8 +315,16 @@ class ServeEngine:
         """One engine iteration: shed/cancel expired work, update the
         degraded-mode ladder, preempt-on-pressure, admit/resume, chunked
         prefill for filling slots, one decode step for decode-ready slots."""
-        self.steps += 1
-        self._now = self.clock()
+        with self.spans.span("engine.step"):
+            self.steps += 1
+            self._now = self.clock()
+            with self.spans.span("engine.schedule"):
+                self._schedule()
+            if self.active:
+                self._prefill_chunks()
+                self._decode()
+
+    def _schedule(self) -> None:
         if self.faults is not None:
             for death in self.faults.deaths_at(self.steps):
                 self.on_rank_death(death.rank, graceful=death.graceful)
@@ -321,10 +333,6 @@ class ServeEngine:
             self.slo_ctl.update_pressure(len(self.queue), self.steps)
         self._maybe_preempt()
         self._admit()
-        if not self.active:
-            return
-        self._prefill_chunks()
-        self._decode()
 
     # -- deadline shedding / cancellation (SLO layer) -----------------------
     def _shed(self, req: GenRequest, reason: str) -> None:
@@ -343,7 +351,7 @@ class ServeEngine:
             self.free_slots.append(slot)
             self.pending[slot, 0] = 0
             self.host_pos[slot] = 0
-            self.cache["pos"] = jnp.asarray(self.host_pos.copy())
+            self._upload_pos(self.host_pos.copy())
         elif req in self.preempted:
             self.preempted.remove(req)
         if req.kv is not None:
@@ -650,13 +658,14 @@ class ServeEngine:
             if req.fed >= plen:
                 continue
             take = min(cap, plen - req.fed)
-            toks = np.zeros((1, self.chunk), np.int32)
-            toks[0, :take] = req.prompt[req.fed:req.fed + take]
-            with use_default(self.dctx):
-                logits, sl = self.chunk_step(
-                    self.params, jnp.asarray(toks), self._slot_cache(slot),
-                    jnp.asarray(take, jnp.int32))
-            self._write_slot(slot, sl)
+            with self.spans.span("engine.prefill.chunk"):
+                toks = np.zeros((1, self.chunk), np.int32)
+                toks[0, :take] = req.prompt[req.fed:req.fed + take]
+                with use_default(self.dctx):
+                    logits, sl = self.chunk_step(
+                        self.params, jnp.asarray(toks),
+                        self._slot_cache(slot), jnp.asarray(take, jnp.int32))
+                self._write_slot(slot, sl)
             req.fed += take
             req.kv.pos += take          # rows actually written, nothing else
             self.host_pos[slot] = req.fed
@@ -665,11 +674,30 @@ class ServeEngine:
             if req.fed >= plen:
                 # the final chunk's last-position logits commit the first
                 # generated token (prefill produces token 1 of max_new)
+                with self.spans.span("engine.prefill.wait"):
+                    jax.block_until_ready(logits)
                 row = np.asarray(jax.device_get(logits))[0, 0]
                 self._commit(slot, req, row)
 
     # -- decode -------------------------------------------------------------
     def _decode(self) -> None:
+        with self.spans.span("engine.decode.prepare"):
+            ready = self._decode_prepare()
+        if not ready:
+            return
+        with self.spans.span("engine.decode.call"):
+            with use_default(self.dctx):
+                logits, self.cache = self.decode_step(
+                    self.params, jnp.asarray(self.pending), self.cache)
+            jax.block_until_ready(logits)
+        self.device_calls += 1
+        with self.spans.span("engine.decode.sample"):
+            self._decode_sample(ready, logits)
+
+    def _decode_prepare(self) -> List[int]:
+        """Page capacity for every decode-ready slot, their pending tokens,
+        and the device positions with the other slots parked; returns the
+        slots that decode."""
         if self.chunk_step is None:
             ready = sorted(self.active)
         else:
@@ -692,7 +720,7 @@ class ServeEngine:
                     break
         ready = [s for s in ready if s in self.active]
         if not ready:
-            return
+            return ready
         for slot in ready:
             req = self.active[slot]
             if self.chunk_step is None and req.fed < len(req.prompt):
@@ -704,11 +732,10 @@ class ServeEngine:
         dev_pos = np.full((self.B,), self.S - 1, np.int32)
         for slot in ready:
             dev_pos[slot] = self.host_pos[slot]
-        self.cache["pos"] = jnp.asarray(dev_pos)
-        with use_default(self.dctx):
-            logits, self.cache = self.decode_step(
-                self.params, jnp.asarray(self.pending), self.cache)
-        self.device_calls += 1
+        self._upload_pos(dev_pos)
+        return ready
+
+    def _decode_sample(self, ready: List[int], logits) -> None:
         rows = np.asarray(jax.device_get(logits))
         for slot in ready:
             req = self.active.get(slot)
@@ -723,7 +750,7 @@ class ServeEngine:
                     continue               # still prefilling: ignore logits
             self._commit(slot, req, rows[slot, 0])
         # authoritative positions back onto the device (parked slots kept)
-        self.cache["pos"] = jnp.asarray(self.host_pos.copy())
+        self._upload_pos(self.host_pos.copy())
 
     # -- commit / sampling / release ----------------------------------------
     def _sample(self, req: GenRequest, row: np.ndarray) -> int:
@@ -740,8 +767,6 @@ class ServeEngine:
         return int(req._rng.choice(keep, p=p))
 
     def _commit(self, slot: int, req: GenRequest, row: np.ndarray) -> None:
-        if not req.out:
-            req.first_logits = row
         req.out.append(self._sample(req, row))
         now = self.clock()
         if req.first_token_t is None:
@@ -766,7 +791,11 @@ class ServeEngine:
         # both behind, so freed slots kept teacher-forcing garbage)
         self.pending[slot, 0] = 0
         self.host_pos[slot] = 0
-        self.cache["pos"] = jnp.asarray(self.host_pos.copy())
+        self._upload_pos(self.host_pos.copy())
+
+    def _upload_pos(self, pos: np.ndarray) -> None:
+        self.cache["pos"] = jnp.asarray(pos)
+        self.spans.count("engine.pos_uploads")
 
     # -- introspection -------------------------------------------------------
     @property

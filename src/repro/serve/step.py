@@ -1,5 +1,8 @@
 """Serve-step builders: shard_map'd prefill and decode steps per family.
 
+The programs are jitted as ``serve_decode``, ``serve_prefill_chunk`` and
+``serve_prefill``, the names a profiler trace and a dump show them under.
+
 The decode step is THE unit the decode_32k / long_500k dry-run cells lower:
 one new token against a full KV cache, with the cache sharded per the
 runtime's placement rules (heads over "model"; batch over DP axes; the S
@@ -57,13 +60,13 @@ def build_decode_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx, *,
         cspecs["pos"] = P(bpart)
     vs = "model" if sch.vocab_sharded(cfg) else None
 
-    def step(params, tokens, cache):
+    def serve_decode(params, tokens, cache):
         logits, cache = decode(params, tokens, cfg, ctx, cache,
                                seq_sharded=seq_sharded)
         return logits, cache
 
     mapped = shard_map(
-        step, mesh=mesh,
+        serve_decode, mesh=mesh,
         in_specs=(pspecs, P(bpart), cspecs),
         out_specs=(P(bpart, None, vs), cspecs),
     )
@@ -104,12 +107,12 @@ def build_chunk_prefill_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx,
     _, cspecs = model_api.cache_structs(cfg, mesh, ctx, B, S_cache)
     vs = "model" if sch.vocab_sharded(cfg) else None
 
-    def step(params, tokens, cache, rlen):
+    def serve_prefill_chunk(params, tokens, cache, rlen):
         return transformer_chunk_prefill(params, tokens, cfg, ctx, cache,
                                          rlen)
 
     mapped = shard_map(
-        step, mesh=mesh,
+        serve_prefill_chunk, mesh=mesh,
         in_specs=(pspecs, P(None), cspecs, P()),
         out_specs=(P(None, None, vs), cspecs),
     )
@@ -146,18 +149,18 @@ def build_prefill_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx, *,
     vs = "model" if sch.vocab_sharded(cfg) else None
 
     if cfg.family in model_api.TRANSFORMER_FAMILIES:
-        def step(params, tokens, cache):
+        def serve_prefill(params, tokens, cache):
             logits, cache = transformer_prefill(
                 params, tokens, cfg, ctx, cache, seq_sharded=seq_sharded)
             return logits, cache
     elif cfg.family == "ssm":
-        def step(params, tokens, cache):
+        def serve_prefill(params, tokens, cache):
             h, cache = rwkv_forward(params, tokens, cfg, ctx, cache)
             logits = jnp.dot(h[:, -1:].astype(jnp.float32),
                              params["lm_head"].astype(jnp.float32))
             return logits, cache
     elif cfg.family == "hybrid":
-        def step(params, tokens, cache):
+        def serve_prefill(params, tokens, cache):
             h, cache = zamba_forward(params, tokens, cfg, ctx, cache,
                                      seq_sharded=seq_sharded)
             logits = jnp.dot(h[:, -1:].astype(jnp.float32),
@@ -167,7 +170,7 @@ def build_prefill_step(cfg: ModelConfig, mesh: Mesh, ctx: ParallelCtx, *,
         raise ValueError(cfg.family)
 
     mapped = shard_map(
-        step, mesh=mesh,
+        serve_prefill, mesh=mesh,
         in_specs=(pspecs, P(bpart), cspecs),
         out_specs=(P(bpart, None, vs), cspecs),
     )
