@@ -125,6 +125,9 @@ class ParallelCtx:
     dp_group: DiompGroup
     ep_group: DiompGroup
     world: DiompGroup
+    device: object                # a device of the mesh: a layer loop that
+    #                               carries a cache keeps it in the layout
+    #                               this device stores it in
     pod_group: Optional[DiompGroup] = None
 
     # knobs (the §Perf hillclimb surface)
@@ -190,6 +193,7 @@ class ParallelCtx:
 
         g = standard_groups(mesh)
         shape = dict(mesh.shape)
+        knobs["device"] = mesh.devices.flat[0]
         tp = shape.get("model", 1)
         fsdp = shape.get("data", 1)
         pods = shape.get("pod", 1)

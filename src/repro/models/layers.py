@@ -38,7 +38,7 @@ __all__ = [
     "rmsnorm", "layernorm", "rope", "gather_fsdp", "tp_allreduce",
     "col_matmul", "row_matmul", "embed_lookup", "ce_loss",
     "attention_block", "mla_block", "mlp_block", "moe_block",
-    "moe_capacity", "cp_decode_attention",
+    "moe_capacity", "decode_attention",
 ]
 
 F32 = jnp.float32
@@ -279,17 +279,68 @@ def ce_loss(h, head_local, targets, cfg: ModelConfig, ctx: ParallelCtx,
 # attention
 # ---------------------------------------------------------------------------
 
+def layer_view(c, layer):
+    """One layer of a cache array: ``c`` itself, or with ``layer`` given,
+    slice ``layer`` of the stack (L, B, S, ...) read where it lies."""
+    if layer is None:
+        return c
+    return lax.dynamic_index_in_dim(c, layer, 0, keepdims=False)
+
+
+def write_rows(c, new, pos, layer=None):
+    """Write ``new`` (B, T, ...) into the cache array ``c`` at sequence
+    position ``pos``: a scalar, or a (B,) vector of per-slot positions
+    (continuous batching).  ``c`` is one layer (B, S, ...) or, with
+    ``layer`` given, the stack (L, B, S, ...); only the new rows are
+    written, the rest of the stack is not rebuilt."""
+    new = new.astype(c.dtype)
+    lead = () if layer is None else (layer,)
+    tail = (0,) * (new.ndim - 2)
+    if layer is not None:
+        new = new[None]
+    if jnp.ndim(pos) == 0:
+        return lax.dynamic_update_slice(c, new, lead + (0, pos) + tail)
+    # one write per slot: a vmapped write would lower to a scatter, whose
+    # expansion forces the whole cache into a padded row-major layout
+    for b in range(pos.shape[0]):
+        c = lax.dynamic_update_slice(
+            c, lax.slice_in_dim(new, b, b + 1, axis=len(lead)),
+            lead + (b, pos[b]) + tail)
+    return c
+
+
 @dataclasses.dataclass
 class KVCache:
-    """Decode-time cache; a pytree (flax-free).  ``pos`` is a traced scalar."""
+    """Decode-time cache; a pytree (flax-free).  ``pos`` is a traced scalar
+    or a (B,) vector of per-slot positions.
 
-    k: jax.Array            # (B, S_cache_local, KH_local, D)
+    ``k`` / ``v`` hold one layer (B, S_cache_local, KH_local, D), or, with
+    ``layer`` (a traced index) set, the whole stack (L, B, S, KH, D) that
+    the layer loop carries: the layer reads its slice where it lies
+    (:meth:`view`) and writes only its new rows (:func:`write_rows`).
+    """
+
+    ARRAYS = ("k", "v")
+
+    k: jax.Array
     v: jax.Array
-    pos: jax.Array          # ()
+    pos: jax.Array
+    layer: Optional[jax.Array] = None
     seq_sharded: bool = False   # context-parallel cache (S split over a group)
 
+    def view(self):
+        """This layer's (k, v), each (B, S, KH, D)."""
+        return layer_view(self.k, self.layer), layer_view(self.v, self.layer)
+
+    def write(self, k_new, v_new, pos, new_pos):
+        """The cache with ``k_new`` / ``v_new`` (B, T, KH, D) written at
+        ``pos`` and its position set to ``new_pos``."""
+        return dataclasses.replace(
+            self, k=write_rows(self.k, k_new, pos, self.layer),
+            v=write_rows(self.v, v_new, pos, self.layer), pos=new_pos)
+
     def tree_flatten(self):
-        return (self.k, self.v, self.pos), (self.seq_sharded,)
+        return (self.k, self.v, self.pos, self.layer), (self.seq_sharded,)
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
@@ -301,39 +352,40 @@ jax.tree_util.register_pytree_node(
 )
 
 
-def cp_decode_attention(q, cache: KVCache, group: DiompGroup, *, scale):
-    """Decode attention over a context(S)-sharded KV cache.
+def decode_attention(q, k, v, valid_len, *, scale,
+                     group: Optional[DiompGroup] = None):
+    """Attention of one query per slot over the cache as it is stored.
 
-    q: (B, 1, H, D); cache.k/v: (B, S/g, KH, D) — each group member holds an
-    S-chunk.  Partial (max, sum, acc) per chunk are combined with OMPCCL
-    max/sum collectives — distributed flash-decode.
+    q: (B, 1, H, D); k: (B, S, KH, D); v: (B, S, KH, Dv); ``valid_len`` a
+    scalar or (B,) count of the rows each slot may see.  Two einsums over
+    the whole layer, with scores, softmax and accumulation in float32 and
+    no reshape of K or V, so the compiler reads them in the cache's own
+    layout (the blockwise reference would relayout every layer).
+
+    With ``group`` given, the cache is context(S)-sharded: k / v are this
+    member's S-chunk (chunks in member order), and the partial (max, sum,
+    acc) are combined with OMPCCL max/sum collectives — distributed
+    flash-decode.
     """
     B, _, H, D = q.shape
-    s_loc = cache.k.shape[1]
-    KH = cache.k.shape[2]
-    Dv = cache.v.shape[-1]
+    S, KH = k.shape[1], k.shape[2]
+    Dv = v.shape[-1]
     G = H // KH
-    ax = group.axes[0]
-    chunk_off = lax.axis_index(ax) * s_loc
-
-    qf = q.astype(F32).reshape(B, KH, G, D) * scale
-    kf = cache.k.astype(F32)
-    s = jnp.einsum("bhgd,bshd->bhgs", qf, kf)                  # (B, KH, G, S/g)
-    k_pos = chunk_off + jnp.arange(s_loc)
-    # cache.pos has already been advanced past the newly written entry, so
-    # exactly the first ``pos`` slots are valid
-    vis = k_pos[None, None, None, :] < cache.pos
+    k_off = 0 if group is None else lax.axis_index(group.axes[0]) * S
+    s = jnp.einsum("bhgd,bshd->bhgs", q.reshape(B, KH, G, D), k,
+                   preferred_element_type=F32) * scale
+    vis = k_off + jnp.arange(S) < jnp.reshape(valid_len, (-1, 1, 1, 1))
     s = jnp.where(vis, s, -jnp.inf)
-
-    m_loc = s.max(axis=-1)
-    m = ompccl.allreduce(m_loc, group, op="max")
-    m_safe = jnp.where(jnp.isneginf(m), 0.0, m)
-    p = jnp.where(vis, jnp.exp(s - m_safe[..., None]), 0.0)
-    l = ompccl.allreduce(p.sum(axis=-1), group)
-    acc = jnp.einsum("bhgs,bshd->bhgd", p, cache.v.astype(F32))
-    acc = ompccl.allreduce(acc, group)
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.reshape(B, 1, H, Dv).astype(q.dtype)
+    m = s.max(axis=-1, keepdims=True)
+    if group is not None:
+        m = ompccl.allreduce(m, group, op="max")
+    p = jnp.where(vis, jnp.exp(s - jnp.where(jnp.isneginf(m), 0.0, m)), 0.0)
+    l = p.sum(axis=-1, keepdims=True)
+    acc = jnp.einsum("bhgs,bshd->bhgd", p, v, preferred_element_type=F32)
+    if group is not None:
+        l = ompccl.allreduce(l, group)
+        acc = ompccl.allreduce(acc, group)
+    return (acc / jnp.maximum(l, 1e-30)).reshape(B, 1, H, Dv).astype(q.dtype)
 
 
 def _update_cache(cache: KVCache, k_new, v_new, group: Optional[DiompGroup]):
@@ -342,31 +394,21 @@ def _update_cache(cache: KVCache, k_new, v_new, group: Optional[DiompGroup]):
     ``cache.pos`` may be a scalar (uniform batch) or a (B,) vector
     (continuous batching: per-slot positions).
     """
-    if jnp.ndim(cache.pos) == 1:  # per-slot positions
-        def write(c, new, p):
-            return lax.dynamic_update_slice(c, new.astype(c.dtype), (p, 0, 0))
-
-        k = jax.vmap(write)(cache.k, k_new, cache.pos)
-        v = jax.vmap(write)(cache.v, v_new, cache.pos)
-        return KVCache(k, v, cache.pos + 1, seq_sharded=cache.seq_sharded)
-    if cache.seq_sharded:
-        assert group is not None
-        s_loc = cache.k.shape[1]
-        lo = lax.axis_index(group.axes[0]) * s_loc
-        local = jnp.clip(cache.pos - lo, 0, s_loc - 1)
-        in_range = (cache.pos >= lo) & (cache.pos < lo + s_loc)
-        k_w = lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype),
-                                       (0, local, 0, 0))
-        v_w = lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype),
-                                       (0, local, 0, 0))
-        k = jnp.where(in_range, k_w, cache.k)
-        v = jnp.where(in_range, v_w, cache.v)
-    else:
-        k = lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype),
-                                     (0, cache.pos, 0, 0))
-        v = lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype),
-                                     (0, cache.pos, 0, 0))
-    return KVCache(k, v, cache.pos + 1, seq_sharded=cache.seq_sharded)
+    if jnp.ndim(cache.pos) == 1 or not cache.seq_sharded:
+        return cache.write(k_new, v_new, cache.pos, cache.pos + 1)
+    assert group is not None
+    # the row lands on the member whose S-chunk holds pos; the others
+    # write their own row back
+    k, v = cache.view()
+    s_loc = k.shape[1]
+    lo = lax.axis_index(group.axes[0]) * s_loc
+    local = jnp.clip(cache.pos - lo, 0, s_loc - 1)
+    in_range = (cache.pos >= lo) & (cache.pos < lo + s_loc)
+    k_old = lax.dynamic_slice_in_dim(k, local, 1, axis=1)
+    v_old = lax.dynamic_slice_in_dim(v, local, 1, axis=1)
+    return cache.write(jnp.where(in_range, k_new.astype(k.dtype), k_old),
+                       jnp.where(in_range, v_new.astype(v.dtype), v_old),
+                       local, cache.pos + 1)
 
 
 def local_kv_heads(cfg: ModelConfig, ctx: ParallelCtx) -> int:
@@ -409,8 +451,9 @@ def attention_block(
     * head-parallel  — q heads divide MAX_TP: heads sharded over "model";
     * token-parallel — otherwise (e.g. paligemma H=8): weights replicated
       over "model", the T axis is sliced instead;
-    * decode         — T == 1 with a cache: head-sharded, replicated, or
-      context(S)-sharded cache (cp_decode_attention);
+    * decode         — T == 1 with a cache (decode_attention, over the
+      cache as stored): head-sharded, replicated, or context(S)-sharded
+      (partials merged over the group);
     * chunked prefill — ``chunked=True`` with a cache and T > 1: the chunk's
       K/V are appended at the running ``cache.pos`` and the queries attend
       over the whole valid prefix (cached + chunk), so a prompt streams
@@ -470,14 +513,10 @@ def attention_block(
                 cache, k, v,
                 ctx.fsdp_group if cache.seq_sharded else None,
             )
-        if cache.seq_sharded:
-            attn = cp_decode_attention(q, new_cache, ctx.fsdp_group,
-                                       scale=hd ** -0.5)
-        else:
-            attn = flash_attention(
-                q, new_cache.k, new_cache.v, causal=True,
-                q_offset=new_cache.pos - 1, valid_len=new_cache.pos,
-            )  # pos may be scalar or (B,) — the ref kernel broadcasts
+        # pos may be scalar or (B,)
+        attn = decode_attention(
+            q, *new_cache.view(), new_cache.pos, scale=hd ** -0.5,
+            group=ctx.fsdp_group if cache.seq_sharded else None)
     elif chunkfill:
         # chunked prefill: append this chunk's K/V at the running cache
         # position and attend over the whole valid prefix.  Causal masking
@@ -489,14 +528,9 @@ def attention_block(
             "chunked prefill does not support a context-sharded cache"
         p0 = cache.pos
         with jax.named_scope("kv_write"):
-            new_cache = KVCache(
-                lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype),
-                                         (0, p0, 0, 0)),
-                lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype),
-                                         (0, p0, 0, 0)),
-                p0 + T, seq_sharded=False,
-            )
-        s_all = new_cache.k.shape[1]
+            new_cache = cache.write(k, v, p0, p0 + T)
+        k_all, v_all = new_cache.view()
+        s_all = k_all.shape[1]
         if ring_attn and s_all % ctx.tp == 0:
             # sequence-parallel chunked prefill: the cache is replicated
             # over "model", so each rank takes its S-stripe and the chunk's
@@ -505,16 +539,14 @@ def attention_block(
             # valid_len are traced; the ring emulation masks dynamically.
             s_loc = s_all // ctx.tp
             me = lax.axis_index(ctx.tp_group.axes[0])
-            k_str = lax.dynamic_slice_in_dim(new_cache.k, me * s_loc,
-                                             s_loc, axis=1)
-            v_str = lax.dynamic_slice_in_dim(new_cache.v, me * s_loc,
-                                             s_loc, axis=1)
+            k_str = lax.dynamic_slice_in_dim(k_all, me * s_loc, s_loc, axis=1)
+            v_str = lax.dynamic_slice_in_dim(v_all, me * s_loc, s_loc, axis=1)
             attn = flash_attention(
                 q, k_str, v_str, causal=True, impl="ring",
                 group=ctx.tp_group, q_offset=p0, valid_len=p0 + T,
                 q_sharded=False)
         else:
-            attn = flash_attention(q, new_cache.k, new_cache.v, causal=True,
+            attn = flash_attention(q, k_all, v_all, causal=True,
                                    q_offset=p0, valid_len=p0 + T)
     elif token_parallel and ring_attn and cache is None and prefix_len == 0:
         # fused ring attention (token-parallel training): the K/V shards
@@ -534,23 +566,11 @@ def attention_block(
             prefix_len=prefix_len,
         )
         if cache is not None:  # prefill: persist the gathered KV
-            new_cache = KVCache(
-                lax.dynamic_update_slice(
-                    cache.k, k_full.astype(cache.k.dtype), (0, 0, 0, 0)),
-                lax.dynamic_update_slice(
-                    cache.v, v_full.astype(cache.v.dtype), (0, 0, 0, 0)),
-                jnp.asarray(T, jnp.int32), seq_sharded=False,
-            )
+            new_cache = cache.write(k_full, v_full, 0, jnp.asarray(T, jnp.int32))
     else:
         attn = flash_attention(q, k, v, causal=causal, prefix_len=prefix_len)
         if cache is not None:  # prefill into a decode cache
-            new_cache = KVCache(
-                lax.dynamic_update_slice(cache.k, k.astype(cache.k.dtype),
-                                         (0, 0, 0, 0)),
-                lax.dynamic_update_slice(cache.v, v.astype(cache.v.dtype),
-                                         (0, 0, 0, 0)),
-                jnp.asarray(T, jnp.int32), seq_sharded=False,
-            )
+            new_cache = cache.write(k, v, 0, jnp.asarray(T, jnp.int32))
 
     attn2 = attn.reshape(*attn.shape[:2], H_loc * hd)
     if token_parallel:
@@ -572,14 +592,28 @@ def attention_block(
 
 @dataclasses.dataclass
 class MLACache:
-    """Latent cache: c_kv (B, S, kr) + rope'd shared key (B, S, dr)."""
+    """Latent cache: c_kv (B, S, kr) + rope'd shared key (B, S, dr); with
+    ``layer`` set, the stacks (L, B, S, ...) the layer loop carries (as
+    :class:`KVCache`)."""
+
+    ARRAYS = ("c", "kr")
 
     c: jax.Array
     kr: jax.Array
     pos: jax.Array
+    layer: Optional[jax.Array] = None
+
+    def view(self):
+        """This layer's (c, kr), (B, S, kr) and (B, S, dr)."""
+        return layer_view(self.c, self.layer), layer_view(self.kr, self.layer)
+
+    def write(self, c_new, kr_new, pos, new_pos):
+        return dataclasses.replace(
+            self, c=write_rows(self.c, c_new, pos, self.layer),
+            kr=write_rows(self.kr, kr_new, pos, self.layer), pos=new_pos)
 
     def tree_flatten(self):
-        return (self.c, self.kr, self.pos), ()
+        return (self.c, self.kr, self.pos, self.layer), ()
 
     @classmethod
     def tree_unflatten(cls, aux, leaves):
@@ -629,34 +663,20 @@ def mla_block(
 
     new_cache = cache
     if cache is not None and T == 1:
-        # absorbed decode
-        if jnp.ndim(cache.pos) == 1:  # per-slot positions
-            wr = lambda cc, new, p: lax.dynamic_update_slice(
-                cc, new.astype(cc.dtype), (p, 0))
-            new_cache = MLACache(
-                jax.vmap(wr)(cache.c, c, cache.pos),
-                jax.vmap(wr)(cache.kr, k_rope[:, :, 0], cache.pos),
-                cache.pos + 1,
-            )
-        else:
-            new_cache = MLACache(
-                lax.dynamic_update_slice(cache.c, c.astype(cache.c.dtype),
-                                         (0, cache.pos, 0)),
-                lax.dynamic_update_slice(cache.kr, k_rope[:, :, 0].astype(
-                    cache.kr.dtype), (0, cache.pos, 0)),
-                cache.pos + 1,
-            )
+        # absorbed decode (pos scalar or per-slot (B,))
+        new_cache = cache.write(c, k_rope[:, :, 0], cache.pos, cache.pos + 1)
+        c_all, kr_all = new_cache.view()
         q_lat = jnp.einsum("bthn,khn->bthk", q_nope.astype(F32),
                            wkv_b[..., :dn].astype(F32))        # (B,1,H,kr)
         s = jnp.einsum("bthk,bsk->bhs", q_lat,
-                       new_cache.c.astype(F32)) + jnp.einsum(
-            "bthr,bsr->bhs", q_rope.astype(F32), new_cache.kr.astype(F32))
+                       c_all.astype(F32)) + jnp.einsum(
+            "bthr,bsr->bhs", q_rope.astype(F32), kr_all.astype(F32))
         s = s * scale
-        k_pos = jnp.arange(new_cache.c.shape[1])
+        k_pos = jnp.arange(c_all.shape[1])
         vis = k_pos[None, None, :] < jnp.reshape(new_cache.pos, (-1, 1, 1))
         s = jnp.where(vis, s, -jnp.inf)
         p = jax.nn.softmax(s, axis=-1, where=vis)
-        ctx_lat = jnp.einsum("bhs,bsk->bhk", p, new_cache.c.astype(F32))
+        ctx_lat = jnp.einsum("bhs,bsk->bhk", p, c_all.astype(F32))
         attn = jnp.einsum("bhk,khn->bhn", ctx_lat,
                           wkv_b[..., dn:].astype(F32))         # (B,H,dv)
         attn = attn[:, None].astype(x.dtype)                   # (B,1,H,dv)
@@ -665,20 +685,15 @@ def mla_block(
         # decompressed full prefix (causal + q_offset mask the padded tail
         # and the unwritten suffix, exactly as in attention_block)
         p0 = cache.pos
-        new_cache = MLACache(
-            lax.dynamic_update_slice(cache.c, c.astype(cache.c.dtype),
-                                     (0, p0, 0)),
-            lax.dynamic_update_slice(
-                cache.kr, k_rope[:, :, 0].astype(cache.kr.dtype), (0, p0, 0)),
-            p0 + T,
-        )
-        S_all = new_cache.c.shape[1]
-        kv_all = jnp.einsum("bsk,khn->bshn", new_cache.c.astype(F32),
+        new_cache = cache.write(c, k_rope[:, :, 0], p0, p0 + T)
+        c_all, kr_all = new_cache.view()
+        S_all = c_all.shape[1]
+        kv_all = jnp.einsum("bsk,khn->bshn", c_all.astype(F32),
                             wkv_b.astype(F32)).astype(x.dtype)
         k_nope_all, v_all = kv_all[..., :dn], kv_all[..., dn:]
         k_all = jnp.concatenate(
             [k_nope_all,
-             jnp.broadcast_to(new_cache.kr[:, :, None].astype(x.dtype),
+             jnp.broadcast_to(kr_all[:, :, None].astype(x.dtype),
                               (B, S_all, H_loc, dr))], axis=-1)
         qkr = jnp.concatenate([q_nope, q_rope], axis=-1)
         attn = flash_attention(qkr, k_all, v_all, causal=True, scale=scale,
@@ -692,13 +707,8 @@ def mla_block(
         qkr = jnp.concatenate([q_nope, q_rope], axis=-1)
         attn = flash_attention(qkr, k, v, causal=True, scale=scale)
         if cache is not None:  # prefill the latent cache
-            new_cache = MLACache(
-                lax.dynamic_update_slice(cache.c, c.astype(cache.c.dtype),
-                                         (0, 0, 0)),
-                lax.dynamic_update_slice(
-                    cache.kr, k_rope[:, :, 0].astype(cache.kr.dtype), (0, 0, 0)),
-                jnp.asarray(T, jnp.int32),
-            )
+            new_cache = cache.write(c, k_rope[:, :, 0], 0,
+                                    jnp.asarray(T, jnp.int32))
 
     out = row_matmul(attn.reshape(B, -1, H_loc * dv), lp["wo"], ctx)
     return out, new_cache

@@ -6,19 +6,24 @@ remat; heterogeneous stacks (DeepSeek's leading dense layers, the MTP head)
 are separate scans.
 
 Caches are dicts of stacked arrays: {"k": (L, B, S, KH_loc, D), "v": …,
-"pos": ()} so the decode scan threads per-layer slices.
+"pos": ()}.  The layer scan carries the stacks: each layer reads its slice
+where it lies and writes only its new rows, so a decode step moves no
+whole layer of the cache.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.core import ompccl
+from repro.core.compat import typeof
 from .config import ModelConfig, ParallelCtx
 from .layers import (
     KVCache, MLACache, attention_block, ce_loss, embed_lookup, gelu_mlp_block,
@@ -87,7 +92,6 @@ def _scan_stack(x, stack, cfg, ctx, *, moe, mla, positions, prefix_len,
     gathers are Varying->Varying); inference uses invariant gathers so the
     residual stream stays exactly as replicated as it really is.
     """
-    from repro.core.compat import typeof
     from repro.core.context import default_context
     from repro.core.ompccl import ensure_varying
 
@@ -108,13 +112,7 @@ def _scan_stack(x, stack, cfg, ctx, *, moe, mla, positions, prefix_len,
     stats = default_context().dispatch_stats
     thread_stats = stats.active
 
-    def body(carry, xs):
-        h = carry
-        if caches is None:
-            lp = xs
-            cache = None
-        else:
-            lp, cache = xs
+    def layer(h, lp, cache):
         if thread_stats:
             with stats.collect() as ds:
                 h2, new_cache = _layer_body(
@@ -126,14 +124,67 @@ def _scan_stack(x, stack, cfg, ctx, *, moe, mla, positions, prefix_len,
                 h, lp, cfg, ctx, moe=moe, mla=mla, positions=positions,
                 prefix_len=prefix_len, cache=cache, chunked=chunked)
             aux = {}
-        return ensure_varying(h2, world), (new_cache, aux)
+        return ensure_varying(h2, world), new_cache, aux
 
-    if remat:
-        body = jax.checkpoint(body)
-    xs = stack if caches is None else (stack, caches)
-    x, (new_caches, aux) = lax.scan(body, ensure_varying(x, world), xs)
+    if caches is None:
+        def body(h, lp):
+            h2, _, aux = layer(h, lp, None)
+            return h2, aux
+
+        if remat:
+            body = jax.checkpoint(body)
+        x, aux = lax.scan(body, ensure_varying(x, world), stack)
+        new_caches = None
+    else:
+        # the stacked cache rides in the carry, so a layer's writes land in
+        # place; the advanced position leaves as a per-layer output, since
+        # every layer starts from the same one
+        arrays = {k: getattr(caches, k) for k in caches.ARRAYS}
+        stored = {k: _stored_layout(v, ctx.device) for k, v in arrays.items()}
+        # the carry's type is fixed: each stack varies over the axes it came
+        # in with and those its new rows vary over (a cache made inside the
+        # step starts invariant)
+        vma = {k: _vma(v) for k, v in arrays.items()}
+
+        def body(carry, xs):
+            h, arrs = carry
+            lp, l = xs
+            h2, new_cache, aux = layer(
+                h, lp, dataclasses.replace(caches, layer=l, **arrs))
+            # left free, the compiler would relayout the carried stack for
+            # the row writes (a padded copy in and out of the loop)
+            arrs = {k: ensure_varying(getattr(new_cache, k), vma[k])
+                    for k in arrs}
+            arrs = {k: with_layout_constraint(a, stored[k])
+                    for k, a in arrs.items()}
+            return (h2, arrs), (new_cache.pos, aux)
+
+        L = jax.tree.leaves(stack)[0].shape[0]
+        xs = (stack, jnp.arange(L, dtype=jnp.int32))
+        xs0 = jax.tree.map(lambda a: a[0], xs)
+        x = ensure_varying(x, world)
+        while True:
+            init = {k: ensure_varying(v, vma[k]) for k, v in arrays.items()}
+            (_, out), _ = jax.eval_shape(body, (x, init), xs0)
+            grown = {k: tuple(sorted(set(vma[k]) | set(_vma(out[k]))))
+                     for k in out}
+            if grown == vma:
+                break
+            vma = grown
+        (x, arrays), (pos, aux) = lax.scan(body, (x, init), xs)
+        new_caches = dataclasses.replace(caches, pos=pos[-1], **arrays)
     stats.record(**{k: jnp.sum(v) for k, v in aux.items()})
     return x, new_caches
+
+
+def _vma(a) -> tuple:
+    return tuple(sorted(getattr(typeof(a), "vma", ())))
+
+
+def _stored_layout(a, device) -> Layout:
+    """The layout ``device`` stores an array shaped like ``a`` in."""
+    return Layout.from_pjrt_layout(
+        device.client.get_default_layout(a.dtype, a.shape, device))
 
 
 def _make_layer_cache(cfg: ModelConfig, ctx: ParallelCtx, B: int, S: int, L: int,
@@ -173,19 +224,17 @@ def init_cache(cfg: ModelConfig, ctx: ParallelCtx, B_loc: int, S: int,
     return cache
 
 
-def _wrap_cache(cfg, raw, pos, seq_sharded, L):
-    """Build the scan-ready cache object: pos broadcast to (L, ...) so every
-    leaf has a leading layer dim for lax.scan (pos may be scalar or (B,))."""
-    pos_l = jnp.broadcast_to(pos, (L,) + jnp.shape(pos))
+def _wrap_cache(cfg, raw, pos, seq_sharded):
+    """The cache object over whole stacks (pos may be scalar or (B,))."""
     if cfg.attention == "mla":
-        return MLACache(raw["c"], raw["kr"], pos_l)
-    return KVCache(raw["k"], raw["v"], pos_l, seq_sharded=seq_sharded)
+        return MLACache(raw["c"], raw["kr"], pos)
+    return KVCache(raw["k"], raw["v"], pos, seq_sharded=seq_sharded)
 
 
 def _unwrap_cache(cfg, cache_obj):
     if cfg.attention == "mla":
-        return {"c": cache_obj.c, "kr": cache_obj.kr}, cache_obj.pos[0]
-    return {"k": cache_obj.k, "v": cache_obj.v}, cache_obj.pos[0]
+        return {"c": cache_obj.c, "kr": cache_obj.kr}, cache_obj.pos
+    return {"k": cache_obj.k, "v": cache_obj.v}, cache_obj.pos
 
 
 def transformer_forward(
@@ -231,7 +280,7 @@ def transformer_forward(
         dcaches = None
         if cache is not None:
             dcaches = _wrap_cache(cfg, {"c": cache["dense_c"],
-                                        "kr": cache["dense_kr"]}, pos, False, kd)
+                                        "kr": cache["dense_kr"]}, pos, False)
         x, new_d = _scan_stack(
             x, dstack, cfg, ctx, moe=False, mla=cfg.attention == "mla",
             positions=positions, prefix_len=prefix_len, caches=dcaches,
@@ -241,8 +290,7 @@ def transformer_forward(
     if cache is not None:
         raw = {k: v for k, v in cache.items()
                if k in ("k", "v", "c", "kr")}
-        caches = _wrap_cache(cfg, raw, pos, seq_sharded,
-                             cfg.num_layers - kd)
+        caches = _wrap_cache(cfg, raw, pos, seq_sharded)
     x, new_caches = _scan_stack(
         x, stack, cfg, ctx, moe=cfg.moe, mla=cfg.attention == "mla",
         positions=positions, prefix_len=prefix_len, caches=caches,

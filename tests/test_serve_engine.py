@@ -9,12 +9,17 @@ byte accounting against the OMPCCL/RMA call logs.
 
 import numpy as np
 import jax
+import jax.numpy as jnp
 import pytest
+from jax.sharding import PartitionSpec as P
 
 from repro import configs
+from repro.core.compat import shard_map
 from repro.core.context import DiompContext
 from repro.core.groups import DiompGroup
 from repro.core.pgas import GlobalMemory
+from repro.kernels.flash_attention.ref import flash_attention_ref
+from repro.models.layers import decode_attention
 from repro.models import schema as sch
 from repro.models.config import ParallelCtx
 from repro.serve.engine import ServeEngine
@@ -106,6 +111,71 @@ def test_released_slot_keeps_no_stale_state(mesh8, params):
     ref = fresh.submit(late_p, max_new=4)
     fresh.run()
     assert late.done and late.out == ref.out, (late.out, ref.out)
+
+
+# -- decode attention over the cache as stored ----------------------------
+
+@pytest.mark.parametrize("H,KH", [(4, 4), (8, 2)])
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_decode_attention_matches_blockwise_reference(H, KH, dtype):
+    """One query per slot over the whole cache, each slot seeing its own
+    count of rows (1 and S among them), against the blockwise reference."""
+    B, S, D = 4, 24, 16
+    rng = np.random.RandomState(5)
+    q, k, v = (jnp.asarray(rng.randn(*shape), dtype) for shape in
+               ((B, 1, H, D), (B, S, KH, D), (B, S, KH, D)))
+    valid = jnp.asarray([1, 7, S - 1, S], jnp.int32)
+    got = decode_attention(q, k, v, valid, scale=D ** -0.5)
+    want = flash_attention_ref(q, k, v, causal=True, q_offset=valid - 1,
+                               valid_len=valid, block=8)
+    assert got.dtype == q.dtype
+    tol = 2e-5 if dtype == np.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
+def test_decode_attention_over_sharded_cache(ring8, dtype):
+    """The cache's S axis split over eight members, each attending over
+    its chunk and merging over the group, matches the whole-cache result
+    (slots whose rows end inside the first, a middle and the last chunk)."""
+    B, S, H, KH, D = 4, 32, 8, 2, 16
+    rng = np.random.RandomState(9)
+    q, k, v = (jnp.asarray(rng.randn(*shape), dtype) for shape in
+               ((B, 1, H, D), (B, S, KH, D), (B, S, KH, D)))
+    valid = jnp.asarray([1, 6, 17, S], jnp.int32)
+    group = DiompGroup(("x",))
+    sharded = jax.jit(shard_map(
+        lambda q, k, v, n: decode_attention(q, k, v, n, scale=D ** -0.5,
+                                            group=group),
+        mesh=ring8, in_specs=(P(), P(None, "x"), P(None, "x"), P()),
+        out_specs=P()))
+    got = sharded(q, k, v, valid)
+    want = decode_attention(q, k, v, valid, scale=D ** -0.5)
+    assert got.dtype == q.dtype
+    tol = 2e-5 if dtype == np.float32 else 2e-2
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               atol=tol, rtol=tol)
+
+
+def test_greedy_tokens_unchanged_with_slot_on_last_row(mesh8, params):
+    """Greedy output is token for token what the blockwise-reference decode
+    produced.  One request fills the cache to its limit while the other
+    slot, once finished, stays parked on the last row S-1: every decode
+    step writes that row and attends over all S rows."""
+    eng = _engine(mesh8, params, slots=2, max_len=32, prefill_chunk=8)
+    rng = np.random.RandomState(11)
+    long_p = rng.randint(0, CFG.vocab_size, size=7).astype(np.int32)
+    short_p = rng.randint(0, CFG.vocab_size, size=3).astype(np.int32)
+    long_r = eng.submit(long_p, max_new=eng.S - 1 - len(long_p))
+    short_r = eng.submit(short_p, max_new=4)
+    eng.run()
+    assert long_r.out == [82, 126, 145, 38, 66, 83, 126, 145, 38, 66, 83,
+                          126, 145, 38, 9, 139, 47, 30, 129, 127, 116, 134,
+                          46, 85]
+    assert short_r.out == [121, 126, 145, 38]
 
 
 # -- paged allocator -------------------------------------------------------

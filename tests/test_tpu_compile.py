@@ -17,6 +17,7 @@ program runs them.
 """
 
 import os
+import re
 
 import numpy as np
 import pytest
@@ -26,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
+from repro import configs
 from repro.core.groups import DiompGroup
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.moe_dispatch.fused import fused_moe_dispatch_tpu
@@ -37,6 +39,10 @@ from repro.kernels.ring_matmul.fused import (fused_ring_allgather_matmul_tpu,
 from repro.kernels.ring_matmul.kernel import matmul_pallas
 from repro.kernels.stencil.fused import fused_wave_step_tpu
 from repro.kernels.stencil.ops import wave_step
+from repro.models import api as model_api
+from repro.models import schema
+from repro.models.config import ParallelCtx
+from repro.serve.step import build_decode_step
 
 BF16, F32 = jnp.bfloat16, jnp.float32
 
@@ -183,3 +189,70 @@ def test_fused_ring_attention_stablelm_heads(ring4):
 
     g = _struct((1, T, H, D), BF16, NamedSharding(ring4, P(None, "x")))
     _compile(f, g, g, g)
+
+
+# -- one chip: the serving decode program ---------------------------------------
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) ([\w\-]+)\((.*)$")
+
+
+def _hlo_instructions(text):
+    """(computation, name, result type, opcode, operand names) of every
+    instruction of a compiled module's text."""
+    comp = None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            comp = ("ENTRY " if line.startswith("ENTRY") else "") + head[1]
+            continue
+        m = _INSTR.match(line)
+        if m and comp:
+            yield (comp, m[1], m[2], m[3],
+                   re.findall(r"%[\w.\-]+", m[4].split("),")[0]))
+
+
+def _bf16_sizes(result_type):
+    return [int(np.prod([int(d) for d in dims.split(",") if d]))
+            for dims in re.findall(r"bf16\[([\d,]*)\]", result_type)]
+
+
+@pytest.mark.parametrize("B,S", [(8, 512), (4, 2048)])
+def test_decode_keeps_cache_in_place(topo, B, S):
+    """The serving engine's decode program (undonated cache, per-slot
+    positions) at stablelm-3b widths reads each layer's K/V where it lies
+    and writes only the new rows: no scheduled op inside the program's
+    loops yields a whole layer of K or V (B·S·KH·D bf16), and no
+    dynamic-update-slice writes a whole layer back into the stacked cache.
+    The entry's one copy of the undonated input is allowed."""
+    cfg = configs.get("stablelm-3b")
+    mesh = Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"))
+    ctx = ParallelCtx.from_mesh(mesh, remat=False, inference=True)
+    step = build_decode_step(cfg, mesh, ctx, B=B, S=S, donate=False,
+                             slot_pos=True)
+    rep = NamedSharding(mesh, P())
+    params = {k: _struct(v.shape, v.dtype, rep)
+              for k, v in schema.param_structs(cfg).items()}
+    structs, _ = model_api.cache_structs(cfg, mesh, ctx, B, S)
+    cache = {k: _struct(v.shape, v.dtype, rep) for k, v in structs.items()}
+    cache["pos"] = _struct((B,), jnp.int32, rep)
+    text = step.lower(params, _struct((B, 1), jnp.int32, rep),
+                      cache).compile().as_text()
+
+    layer = B * S * cfg.kv_heads * cfg.head_dim
+    instrs = list(_hlo_instructions(text))
+    fused = set(re.findall(r"calls=(%[\w.\-]+)", text))
+    fused |= set(re.findall(r"to_apply=(%[\w.\-]+)", text))
+    whole_layer = [(c, n, op) for c, n, t, op, _ in instrs
+                   if not c.startswith("ENTRY") and c not in fused
+                   and layer in _bf16_sizes(t)]
+    assert not whole_layer, whole_layer
+
+    result = {n: t for _, n, t, _, _ in instrs}
+    rewrites = [(c, n) for c, n, t, op, args in instrs
+                if op == "dynamic-update-slice" and len(args) > 1
+                and layer in _bf16_sizes(result.get(args[1], ""))]
+    assert not rewrites, rewrites
+    # the text was read: the entry takes the stacked K and V
+    assert sum(c.startswith("ENTRY") and op == "parameter"
+               and _bf16_sizes(t) == [cfg.num_layers * layer]
+               for c, _, t, op, _ in instrs) == 2
