@@ -63,17 +63,33 @@ VMEM_LIMIT_BYTES = 100 * 2**20
 
 LANES, SUBLANES = 128, 8   # minor-dim tile of a 32-bit VMEM array
 
+# Bytes of one grid step's u_prev / velocity / output block that the
+# streamed stencil aims for: enough to amortise a grid step's fixed cost,
+# and no deeper, since deeper blocks measured slower on a TPU v5e (whole
+# 1024² f32 planes: 13.96 ms a step at one plane a block, 16.05 ms at two).
+STENCIL_BLOCK_BYTES = 2**20
+
 
 def _itemsize(dtype) -> int:
     return jnp.dtype(dtype).itemsize
 
 
-def padded_plane(y: int, x: int, halo: int) -> Tuple[int, int]:
-    """(Y, X) extents of a halo-padded plane rounded up to the VMEM tile:
-    Y + 2·halo to a sublane multiple, X + 2·halo to a lane multiple, so a
-    DMA of whole planes never slices a minor dimension."""
-    return (-(-(y + 2 * halo) // SUBLANES) * SUBLANES,
-            -(-(x + 2 * halo) // LANES) * LANES)
+def stencil_halo_rows(radius: int) -> int:
+    """Rows of Y halo a stencil tile stages above and below its core: the
+    radius rounded up to the sublane tile, so each plane DMA and the
+    tile's core rows stay tile-aligned."""
+    return -(-radius // SUBLANES) * SUBLANES
+
+
+def stencil_tile_bytes(bz: int, by: int, x: int, dtype, radius: int) -> int:
+    """VMEM the streamed stencil kernel holds for a ``(bz, by)`` tile: its
+    ring of ``2·radius + 2`` halo-extended planes of the Y tile, and the
+    double-buffered u_prev / velocity / output blocks of ``bz`` planes,
+    each counted at its (8, 128)-tiled size."""
+    yp = -(-by // SUBLANES) * SUBLANES
+    xp = -(-x // LANES) * LANES
+    ring = (2 * radius + 2) * (yp + 2 * stencil_halo_rows(radius)) * xp
+    return (ring + 2 * 3 * bz * yp * xp) * _itemsize(dtype)
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
@@ -860,36 +876,44 @@ class OverlapPlanner:
 
         return plan_for_config(cfg, mesh, ctx)
 
-    # -- stencil slab ---------------------------------------------------------
+    # -- stencil tile ---------------------------------------------------------
+    def plan_stencil_tile(self, z: int, y: int, x: int, dtype,
+                          *, radius: int = 4, bz: int = 8,
+                          budget: Optional[int] = None
+                          ) -> Optional[Tuple[int, int]]:
+        """``(bz, by)`` tile of the streamed stencil kernel, or ``None``.
+
+        ``by`` is the Y tile: the whole plane where its ring fits
+        ``budget`` (no Y halo is then read twice), else the largest
+        sublane-aligned split of Y that does; ``bz`` is the depth of the
+        pipelined u_prev / velocity / output blocks: the fewest planes
+        whose block reaches :data:`STENCIL_BLOCK_BYTES` (at most ``bz``
+        and ``z``), halved until the tile fits.  ``None`` where not even
+        an 8-row tile of one plane fits: the caller then takes the XLA
+        path.  ``budget`` defaults to the planner's; the kernel's
+        dispatchers pass the VMEM limit it compiles with.
+        """
+        budget = self.vmem_budget if budget is None else budget
+        bz = max(min(bz, z), 1)
+        for by in _y_tiles(y):
+            plane = (-(-by // SUBLANES) * SUBLANES * -(-x // LANES) * LANES
+                     * _itemsize(dtype))
+            b = min(bz, -(-STENCIL_BLOCK_BYTES // plane))
+            while b > 1 and stencil_tile_bytes(b, by, x, dtype,
+                                               radius) > budget:
+                b //= 2
+            if stencil_tile_bytes(b, by, x, dtype, radius) <= budget:
+                return b, by
+        return None
+
     def plan_stencil_bz(self, z: int, y: int, x: int, dtype,
                         *, radius: int = 4, bz: int = 8,
                         budget: Optional[int] = None) -> int:
-        """Z-slab height whose halo slab still double-buffers in budget.
-
-        ``budget`` defaults to the planner's; the streamed stencil kernel
-        passes the VMEM limit it compiles with.  The slab is counted at its
-        tile-padded plane (:func:`padded_plane`).  Degenerate inputs fall
-        back instead of producing an invalid plan: ``bz`` exceeding the Z
-        extent clamps to it, a grid shorter than the stencil support still
-        yields a positive slab, and a budget too small for any slab bottoms
-        out at ``bz == 1`` (the kernel then streams one plane at a time —
-        slow, never wrong).
-        """
-        bz = max(min(bz, z), 1)
-        while bz > 1 and not self.stencil_fits(bz, y, x, dtype,
-                                               radius=radius, budget=budget):
-            bz = max(1, bz // 2)
-        return bz
-
-    def stencil_fits(self, bz: int, y: int, x: int, dtype, *,
-                     radius: int = 4, budget: Optional[int] = None) -> bool:
-        """Does a ``bz``-plane slab (counted at its tile-padded plane) plus
-        the u_prev/velocity/output blocks double-buffer in ``budget``?"""
-        budget = self.vmem_budget if budget is None else budget
-        item = _itemsize(dtype)
-        yp, xp = padded_plane(y, x, radius)
-        ws = ((bz + 2 * radius) * yp * xp + 3 * bz * y * x) * item
-        return self.pool.plan_slots(ws, budget) * ws <= budget
+        """Z depth of :meth:`plan_stencil_tile`'s tile; 1 where no tile
+        fits (a Z slab never plans below one plane)."""
+        tile = self.plan_stencil_tile(z, y, x, dtype, radius=radius, bz=bz,
+                                      budget=budget)
+        return tile[0] if tile else 1
 
     # -- halo exchange (Minimod) ----------------------------------------------
     def plan_halo_slots(self, z_loc: int, y_loc: int, x: int, dtype,
@@ -943,6 +967,21 @@ class OverlapPlanner:
             plan = dataclasses.replace(plan, overlap=False, slots=1,
                                        vmem_bytes=stage)
         return plan
+
+
+def _y_tiles(y: int):
+    """Y tile heights to try, largest first: the whole plane, then
+    ``ceil(y / n)`` rounded up to the sublane tile for n = 2, 3, …,
+    down to one sublane tile."""
+    yield y
+    last, n = y, 2
+    while last > SUBLANES:
+        rows = -(-y // n)
+        by = -(-rows // SUBLANES) * SUBLANES
+        if by < last:
+            yield by
+            last = by
+        n += 1
 
 
 _DEFAULT_PLANNER: Optional[OverlapPlanner] = None

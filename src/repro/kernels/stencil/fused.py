@@ -52,13 +52,16 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.backends import payload_bytes
 from repro.core.groups import DiompGroup
 from repro.core.rma import RMAError, halo_window_names, ompx_fence, ompx_put
+from repro.core.spans import recorder
 from repro.core.vma import out_struct, zeros_varying
 from repro.kernels.plan import (LANES, SUBLANES, VMEM_LIMIT_BYTES, HaloPlan,
                                 default_planner, resolve_interpret)
-from .kernel import leap_plane
+from .kernel import leap_plane, wave_step_pallas
 from .ref import COEFFS, RADIUS
 
 __all__ = [
+    "DISPATCH_EMULATION",
+    "DISPATCH_PALLAS",
     "Halos",
     "exchange_halos",
     "fused_wave_step",
@@ -66,6 +69,11 @@ __all__ = [
     "fused_wave_step_interpret",
     "fused_wave_step_tpu",
 ]
+
+
+# trace-time counters of fused_wave_step's dispatch
+DISPATCH_PALLAS = "stencil.dispatch.pallas"
+DISPATCH_EMULATION = "stencil.dispatch.emulation"
 
 
 class Halos(NamedTuple):
@@ -265,11 +273,17 @@ def _boundary(uext, u_prev, c2, *, zv, nz: int, ny: int, dx: float, dtype):
     return lo, hi, y_lo, y_hi
 
 
-def _interior(upad, u_prev, c2, *, nz: int, ny: int, dx: float, dtype):
+def _interior(u, upad, u_prev, c2, *, nz: int, ny: int, dx: float, dtype,
+              tile: Optional[Tuple[int, int]] = None):
     """The halo-independent interior (phase "interior"): computed from the
-    local field alone, so it runs entirely under the in-flight exchange."""
+    local field alone, so it runs entirely under the in-flight exchange.
+    With a ``tile`` (Z-only split), the streamed Pallas kernel computes
+    its output planes ``[R, Z - R)`` from ``u`` itself."""
     R = RADIUS
     Z, Y, X = u_prev.shape
+    if tile is not None:
+        return wave_step_pallas(u, u_prev, c2, dx=dx, tile=tile,
+                                z_range=(R, Z - R))
     zsl = slice(R, Z + R) if nz > 1 else slice(0, Z + 2 * R)
     ysl = slice(R, Y + R) if ny > 1 else slice(0, Y + 2 * R)
     pz = slice(R, Z - R) if nz > 1 else slice(0, Z)
@@ -301,6 +315,7 @@ def fused_wave_step_interpret(
     ygroup: Optional[DiompGroup] = None, *,
     plan: HaloPlan, dx: float = 1.0, halos: Optional[Halos] = None,
     z_extents: Optional[Tuple[int, ...]] = None, return_halos: bool = False,
+    tile: Optional[Tuple[int, int]] = None,
 ):
     """Execute :meth:`HaloPlan.schedule` with ``ompx_put`` as the remote copy.
 
@@ -308,7 +323,9 @@ def fused_wave_step_interpret(
     driver trains and serves through on CPU, and what XLA still compiles
     (and overlaps) on TPU for the configurations the compiled kernel does
     not cover.  With ``return_halos=True`` the step returns
-    ``(u_next, halos_of_u_next)`` for the carried time loop.
+    ``(u_next, halos_of_u_next)`` for the carried time loop.  A ``tile``
+    (TPU, Z-only split) hands the interior phase to the streamed Pallas
+    stencil; the rest of the step is unchanged.
     """
     R = plan.halo
     Z, Y, X = u.shape
@@ -344,8 +361,8 @@ def fused_wave_step_interpret(
         # while the interior computes under it
         started = _halo_puts(_slabs_of(u, zv=zv, nz=nz, ny=ny),
                              zgroup, ygroup, nz=nz, ny=ny)
-        interior = _interior(upad, u_prev, c2, nz=nz, ny=ny, dx=dx,
-                             dtype=dtype)
+        interior = _interior(u, upad, u_prev, c2, nz=nz, ny=ny, dx=dx,
+                             dtype=dtype, tile=tile)
         landed = _fence_halos(started, zgroup, ygroup)
         uext = _assemble(upad, landed, zv=zv, Z=Z, Y=Y, X=X)
         bnd = _boundary(uext, u_prev, c2, zv=zv, nz=nz, ny=ny, dx=dx,
@@ -360,7 +377,8 @@ def fused_wave_step_interpret(
     bnd = _boundary(uext, u_prev, c2, zv=zv, nz=nz, ny=ny, dx=dx, dtype=dtype)
     started = _halo_puts((bnd[0], bnd[1], bnd[2], bnd[3]), zgroup, ygroup,
                          nz=nz, ny=ny)
-    interior = _interior(upad, u_prev, c2, nz=nz, ny=ny, dx=dx, dtype=dtype)
+    interior = _interior(u, upad, u_prev, c2, nz=nz, ny=ny, dx=dx,
+                         dtype=dtype, tile=tile)
     new_halos = _fence_halos(started, zgroup, ygroup)
     out = _combine(interior, bnd, u, zv=zv, nz=nz, ny=ny)
     return (out, new_halos) if return_halos else out
@@ -531,11 +549,17 @@ def fused_wave_step(
     the carried-halo state of the multi-step time loop.
 
     Off the interpreter, a single rank (no exchanging axis) runs the
-    slab-streamed Pallas stencil (:func:`~repro.kernels.stencil.kernel.
-    wave_step_pallas`); configurations the compiled fused kernel does not
-    cover (2-D, asymmetric, carried halos, shards over ``VMEM_LIMIT_BYTES``)
-    route through the emulation — which XLA still compiles on TPU.  Neither
-    Pallas path has a VJP: differentiate through ``interpret=True``.
+    streamed Pallas stencil (:func:`~repro.kernels.stencil.kernel.
+    wave_step_pallas`) wherever the planner finds it a tile
+    (:meth:`~repro.kernels.plan.OverlapPlanner.plan_stencil_tile`);
+    configurations the compiled fused kernel does not cover (2-D,
+    asymmetric, carried halos, shards over ``VMEM_LIMIT_BYTES``) route
+    through the emulation — which XLA still compiles on TPU — with its
+    interior in the streamed kernel for a Z-only split.  Each trace counts
+    the path it took in :func:`repro.core.spans.recorder` (``stencil.
+    dispatch.pallas`` where a Pallas kernel computes the star, ``stencil.
+    dispatch.emulation`` where XLA's fusion of it does).  No Pallas path
+    has a VJP: differentiate through ``interpret=True``.
     """
     from repro.core.compat import axis_size
 
@@ -569,27 +593,36 @@ def fused_wave_step(
     if plan.halo != RADIUS:
         raise ValueError(f"plan.halo={plan.halo} != stencil radius {RADIUS}")
 
-    if resolve_interpret(interpret):
-        return fused_wave_step_interpret(
-            u, u_prev, c2dt2, zgroup, ygroup, plan=plan, dx=dx,
-            halos=halos, z_extents=z_extents, return_halos=return_halos)
-    if not plan.exchange_axes and z_extents is None and \
-            default_planner().stencil_fits(1, Y, X, u.dtype, radius=RADIUS,
-                                           budget=VMEM_LIMIT_BYTES):
-        # one rank holds the whole grid: nothing to exchange, and the
-        # streamed slab kernel holds a one-plane slab of this width
-        from .ops import wave_step
-        out = wave_step(u, u_prev, c2dt2, dx=dx, impl="pallas",
-                        interpret=False)
+    interp = resolve_interpret(interpret)
+    # the streamed kernel's tile, for a Z-only split or a lone rank: the
+    # whole field, or the halo-independent interior planes [R, Z - R);
+    # the kernel keeps X whole on the lanes
+    tile = None
+    if not interp and ny == 1 and z_extents is None and X % LANES == 0:
+        tile = default_planner().plan_stencil_tile(
+            Z if nz == 1 else Z - 2 * RADIUS, Y, X, u.dtype, radius=RADIUS,
+            budget=VMEM_LIMIT_BYTES)
+    if tile is not None and not plan.exchange_axes:
+        # one rank holds the whole grid: nothing to exchange
+        recorder().count(DISPATCH_PALLAS)
+        out = wave_step_pallas(u, u_prev, c2dt2, dx=dx, tile=tile)
         return (out, None) if return_halos else out
     # the compiled fused kernel keeps u/u_prev/c2/out + the halo landing
-    # windows wholly resident in VMEM; larger shards take the emulation,
-    # which XLA pipelines through HBM on TPU
-    if (ny > 1 or z_extents is not None or halos is not None or return_halos
-            or fused_resident_bytes(Z, Y, X, u.dtype, RADIUS)
-            > VMEM_LIMIT_BYTES):
+    # windows wholly resident in VMEM; carried halos and larger shards take
+    # the emulation, which XLA pipelines through HBM on TPU, its interior
+    # in the streamed kernel where a tile fits
+    if interp or (ny > 1 or z_extents is not None or halos is not None
+                  or return_halos
+                  or fused_resident_bytes(Z, Y, X, u.dtype, RADIUS)
+                  > VMEM_LIMIT_BYTES):
+        if not plan.overlap:
+            tile = None
+        recorder().count(DISPATCH_EMULATION if tile is None
+                         else DISPATCH_PALLAS)
         return fused_wave_step_interpret(
             u, u_prev, c2dt2, zgroup, ygroup, plan=plan, dx=dx,
-            halos=halos, z_extents=z_extents, return_halos=return_halos)
+            halos=halos, z_extents=z_extents, return_halos=return_halos,
+            tile=tile)
+    recorder().count(DISPATCH_PALLAS)
     return fused_wave_step_tpu(u, u_prev, c2dt2, axis=zgroup.axes[0],
                                plan=plan, dx=dx)

@@ -1,9 +1,9 @@
 """jit'd public wrapper for the acoustic wave step.
 
-``bz=None`` sizes the Z slab through the shared OverlapPlanner (the halo
-slab must double-buffer inside the VMEM limit the kernel compiles with —
-the StreamPool.plan_slots contract); ``interpret=None`` resolves from the
-backend at call time.
+``impl="pallas"`` plans the kernel's ``(bz, by)`` tile through the shared
+OverlapPlanner against the VMEM limit the kernel compiles with (``bz``
+caps the tile's Z depth); ``interpret=None`` resolves from the backend at
+call time.
 
 The resolution happens HERE, before the jit boundary, so the jit cache is
 keyed on the *resolved* flag rather than on ``None``: a cached trace can
@@ -15,7 +15,7 @@ explicitly resolved value hits the same cache entry.
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 
@@ -29,13 +29,15 @@ from .ref import wave_step_ref
 __all__ = ["wave_step", "fused_wave_step", "exchange_halos"]
 
 
-@functools.partial(jax.jit, static_argnames=("dx", "impl", "bz", "interpret"))
+@functools.partial(jax.jit, static_argnames=("dx", "impl", "tile",
+                                             "interpret"))
 def _wave_step_jit(u, u_prev, c2dt2, *, dx: float, impl: str,
-                   bz: Optional[int], interpret: Optional[bool]):
+                   tile: Optional[Tuple[int, int]],
+                   interpret: Optional[bool]):
     if impl == "ref":
         return wave_step_ref(u, u_prev, c2dt2, dx=dx)
     if impl == "pallas":
-        return wave_step_pallas(u, u_prev, c2dt2, dx=dx, bz=bz,
+        return wave_step_pallas(u, u_prev, c2dt2, dx=dx, tile=tile,
                                 interpret=interpret)
     raise ValueError(impl)
 
@@ -43,15 +45,20 @@ def _wave_step_jit(u, u_prev, c2dt2, *, dx: float, impl: str,
 def wave_step(u, u_prev, c2dt2, *, dx: float = 1.0, impl: str = "ref",
               bz: Optional[int] = None, interpret: Optional[bool] = None):
     """u, u_prev: (Z, Y, X) f32; c2dt2 scalar or (Z, Y, X).  One leapfrog step."""
+    tile = None
     if impl == "pallas":
         interpret = resolve_interpret(interpret)
-        if bz is None:
-            bz = default_planner().plan_stencil_bz(
-                u.shape[0], u.shape[1], u.shape[2], u.dtype, radius=RADIUS,
-                budget=VMEM_LIMIT_BYTES)
+        Z, Y, X = u.shape
+        tile = default_planner().plan_stencil_tile(
+            Z, Y, X, u.dtype, radius=RADIUS, bz=bz or 8,
+            budget=VMEM_LIMIT_BYTES)
+        if tile is None:
+            raise ValueError(
+                f"no stencil tile of a {Y}x{X} plane fits "
+                f"{VMEM_LIMIT_BYTES} B of VMEM: use impl='ref'")
     else:
         # the ref path ignores both knobs: normalize them out of the jit key
         # so explicit values cannot mint duplicate cache entries
-        bz = interpret = None
-    return _wave_step_jit(u, u_prev, c2dt2, dx=dx, impl=impl, bz=bz,
+        interpret = None
+    return _wave_step_jit(u, u_prev, c2dt2, dx=dx, impl=impl, tile=tile,
                           interpret=interpret)

@@ -24,10 +24,16 @@ from repro.core.context import DiompContext, use_default
 from repro.core.groups import DiompGroup
 from repro.core.rma import RMAError
 from repro.core.streams import StreamPool
-from repro.kernels.plan import HaloPlan, OverlapPlanner, default_planner
+from repro.core.spans import recorder
+from repro.kernels.plan import (VMEM_LIMIT_BYTES, HaloPlan, OverlapPlanner,
+                                default_planner, stencil_halo_rows,
+                                stencil_tile_bytes)
+from repro.kernels.stencil import fused as stencil_fused
 from repro.kernels.stencil import ops as stencil_ops
-from repro.kernels.stencil.fused import (Halos, exchange_halos,
+from repro.kernels.stencil.fused import (DISPATCH_EMULATION, DISPATCH_PALLAS,
+                                         Halos, exchange_halos,
                                          fused_wave_step)
+from repro.kernels.stencil.kernel import wave_step_pallas
 from repro.kernels.stencil.ref import RADIUS, wave_step_ref
 
 RNG = np.random.RandomState(0)
@@ -88,7 +94,8 @@ def test_fused_step_matches_reference(Z, Y, X, nz, ny, ext):
 @pytest.mark.parametrize("Z,Y,X,nz", [
     (64, 12, 10, 4),     # the fused kernel: puts under the interior planes
     (32, 12, 10, 4),     # the fused kernel, no interior: all boundary
-    (16, 8, 8, 1),       # one rank: the slab-streamed stencil kernel
+    (16, 8, 8, 1),       # one rank, X off the lane tile: the resident kernel
+    (16, 16, 128, 1),    # one rank: the streamed stencil kernel
 ])
 def test_tpu_kernels_match_reference_in_tpu_interpreter(Z, Y, X, nz):
     """The compiled path's kernel bodies (remote copies and semaphores
@@ -256,11 +263,117 @@ def test_plan_stencil_bz_degenerate():
     planner = default_planner()
     # bz exceeding the Z extent clamps to it
     assert planner.plan_stencil_bz(3, 8, 8, jnp.float32, bz=64) == 3
+    assert planner.plan_stencil_tile(3, 8, 8, jnp.float32, bz=64) == (3, 8)
     # grid smaller than the stencil support still yields a positive slab
     assert planner.plan_stencil_bz(2, 2, 2, jnp.float32) >= 1
+    assert planner.plan_stencil_tile(2, 2, 2, jnp.float32) == (2, 2)
     # budget too small for any slab bottoms out at one plane
     tiny = OverlapPlanner(pool=StreamPool(max_active=8), vmem_budget=256)
     assert tiny.plan_stencil_bz(64, 64, 64, jnp.float32) == 1
+    # ... and has no tile at all: the dispatcher takes the XLA path
+    assert tiny.plan_stencil_tile(64, 64, 64, jnp.float32) is None
+    # not even 8 rows of a very wide plane fit the kernel's limit
+    assert planner.plan_stencil_tile(8, 64, 131072, jnp.float32,
+                                     budget=VMEM_LIMIT_BYTES) is None
+    # whole planes where they fit: 512² and Minimod's 1024² share
+    for z, y in ((512, 512), (256, 1024)):
+        bz, by = planner.plan_stencil_tile(z, y, y, jnp.float32,
+                                           budget=VMEM_LIMIT_BYTES)
+        assert by == y and 1 <= bz <= 8
+        assert stencil_tile_bytes(bz, by, y, jnp.float32, RADIUS) \
+            <= VMEM_LIMIT_BYTES
+    # a plane whose ring does not fit whole tiles Y on sublanes, with
+    # the Y halo (8 rows a side) re-read under 7%
+    bz, by = planner.plan_stencil_tile(256, 2048, 2048, jnp.float32,
+                                       budget=VMEM_LIMIT_BYTES)
+    hy = stencil_halo_rows(RADIUS)
+    assert by < 2048 and by % 8 == 0 and (by + 2 * hy) / by <= 1.07
+    assert stencil_tile_bytes(bz, by, 2048, jnp.float32, RADIUS) \
+        <= VMEM_LIMIT_BYTES
+
+
+@pytest.mark.parametrize("Z,Y,X,tile,z_range", [
+    (12, 16, 128, (4, 16), None),        # whole plane, by == Y
+    (12, 40, 128, (5, 16), None),        # by < Y, ragged Y tile and Z block
+    (17, 32, 256, (8, 8), None),         # 4 Y tiles of 8 rows, 256 lanes
+    (16, 24, 256, (4, 8), (4, 12)),      # interior, first/middle/last tiles
+    (20, 24, 128, (8, 24), (4, 16)),     # interior, bz cut to divide R
+])
+def test_streamed_stencil_in_tpu_interpreter(Z, Y, X, tile, z_range):
+    """The streamed kernel's body (DMA ring, semaphores, Dirichlet faces)
+    against the oracle, and its output-Z-range variant against the
+    emulation's interior phase, which it replaces on the chip."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    u = RNG.randn(Z, Y, X).astype(np.float32)
+    up = RNG.randn(Z, Y, X).astype(np.float32)
+    c2 = RNG.uniform(0.05, 0.2, (Z, Y, X)).astype(np.float32)
+    with pltpu.force_tpu_interpret_mode():
+        got = np.asarray(jax.jit(lambda a, b, c: wave_step_pallas(
+            a, b, c, tile=tile, z_range=z_range))(u, up, c2))
+    want = np.asarray(wave_step_ref(u, up, c2))
+    if z_range is not None:
+        z0, z1 = z_range
+        interior = stencil_fused._interior(
+            u, jnp.pad(u, RADIUS), up, c2, nz=2, ny=1, dx=1.0,
+            dtype=jnp.float32)
+        np.testing.assert_allclose(got, np.asarray(interior), atol=2e-6)
+        want = want[z0:z1]
+    assert got.shape == want.shape
+    np.testing.assert_allclose(got, want, atol=2e-6)
+
+
+def _dispatch_counts():
+    c = recorder().counters()
+    return np.array([c.get(DISPATCH_PALLAS, 0), c.get(DISPATCH_EMULATION, 0)])
+
+
+def test_dispatch_counts_pallas_and_emulation():
+    """Off the interpreter the dispatcher takes the streamed kernel where
+    the planner has a tile (counted ``stencil.dispatch.pallas``): the
+    whole field of a lone rank, and the interior of the carried split
+    step; a plane too wide for any tile takes the emulation (counted
+    ``stencil.dispatch.emulation``)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    n0 = _dispatch_counts()
+    with pltpu.force_tpu_interpret_mode():
+        _run_step(16, 16, 128, 1, interpret=False, check_vma=False)
+    assert list(_dispatch_counts() - n0) == [1, 0]
+
+    # carried halos over four ranks: the interior in the kernel
+    grid, steps = (64, 16, 128), 3
+    u0 = (RNG.randn(*grid) * 0.1).astype(np.float32)
+    want = _reference(u0, np.zeros(grid, np.float32), 0.1, steps)
+    mesh = make_mesh((4, 1), ("z", "y"), axis_types="auto")
+
+    def loop(u, up):
+        h = exchange_halos(u, ZG)
+        for _ in range(steps):
+            un, h = fused_wave_step(u, up, 0.1, ZG, halos=h,
+                                    return_halos=True, interpret=False)
+            u, up = un, u
+        return u
+
+    f = jax.jit(shard_map(loop, mesh=mesh, in_specs=(P("z", "y"),) * 2,
+                          out_specs=P("z", "y"), check_vma=False))
+    n0 = _dispatch_counts()
+    with use_default(DiompContext(mesh=mesh)), \
+            pltpu.force_tpu_interpret_mode():
+        got = np.asarray(f(u0, np.zeros(grid, np.float32)))
+    assert list(_dispatch_counts() - n0) == [steps, 0]
+    np.testing.assert_allclose(got, want, atol=3e-6)
+
+    # no 8-row tile of this plane fits VMEM_LIMIT_BYTES: the emulation
+    mesh1 = make_mesh((1, 1), ("z", "y"), axis_types="auto")
+    wide = jax.ShapeDtypeStruct((8, 8, 131072), jnp.float32)
+    step = shard_map(
+        lambda a, b: fused_wave_step(a, b, 0.1, ZG, interpret=False),
+        mesh=mesh1, in_specs=(P("z", "y"),) * 2, out_specs=P("z", "y"))
+    n0 = _dispatch_counts()
+    with use_default(DiompContext(mesh=mesh1)):
+        jax.eval_shape(step, wide, wide)
+    assert list(_dispatch_counts() - n0) == [0, 1]
 
 
 def test_fused_step_rejects_halo_wider_than_shard():

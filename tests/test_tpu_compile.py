@@ -16,6 +16,7 @@ The fused kernels compile under ``shard_map``'s vma checking, as the
 program runs them.
 """
 
+import json
 import os
 import re
 
@@ -28,15 +29,19 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
                           SingleDeviceSharding)
 
 from repro import configs
+from repro.core.compat import shard_map
+from repro.core.context import DiompContext, use_default
 from repro.core.groups import DiompGroup
 from repro.kernels.flash_attention.kernel import flash_attention_pallas
 from repro.kernels.moe_dispatch.fused import fused_moe_dispatch_tpu
-from repro.kernels.plan import OverlapPlanner, RingPlan, VMEM_LIMIT_BYTES
+from repro.kernels.plan import (OverlapPlanner, RingPlan, VMEM_LIMIT_BYTES,
+                                default_planner)
 from repro.kernels.ring_attention.fused import (
     fused_ring_attention_resident_bytes, fused_ring_attention_tpu)
 from repro.kernels.ring_matmul.fused import (fused_ring_allgather_matmul_tpu,
                                              fused_ring_resident_bytes)
 from repro.kernels.ring_matmul.kernel import matmul_pallas
+from repro.kernels.stencil import fused as stencil_fused
 from repro.kernels.stencil.fused import fused_wave_step_tpu
 from repro.kernels.stencil.ops import wave_step
 from repro.models import api as model_api
@@ -111,6 +116,69 @@ def test_wave_step_full_grid(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 16e9
 
 
+def _minimod_program(devices, K=10):
+    """Minimod's K-step program as the chip benchmark compiles it: the
+    carried-halo schedule over a Z split of 256 × 1024² per chip (the
+    whole field on one), ``fused_wave_step`` dispatching off the
+    interpreter.  Returns the compiled module's text."""
+    from jax import lax
+
+    n = len(devices)
+    Z, Y, X = 256 * n, 1024, 1024
+    mesh = Mesh(np.array(devices).reshape(n, 1), ("z", "y"))
+    zg = DiompGroup(("z",), name="z")
+    plan = default_planner().plan_halo_slots(Z // n, Y, X, F32, n)
+    spec = P("z", "y")
+
+    def kprog(u, up, c2, *h):
+        def body(carry, _):
+            u, up, h = carry
+            if plan.overlap:
+                un, h = stencil_fused.fused_wave_step(
+                    u, up, c2, zg, plan=plan, halos=stencil_fused.Halos(*h),
+                    return_halos=True, interpret=False)
+                h = (h.z_lo, h.z_hi)
+            else:
+                un = stencil_fused.fused_wave_step(u, up, c2, zg, plan=plan,
+                                                   interpret=False)
+            return (un, u, h), None
+
+        (u, up, h), _ = lax.scan(body, (u, up, h), None, length=K)
+        return (u, up) + tuple(h)
+
+    sh = NamedSharding(mesh, spec)
+    field = _struct((Z, Y, X), F32, sh)
+    halos = (_struct((4 * n, Y, X), F32, sh),) * 2 if plan.overlap else ()
+    with use_default(DiompContext(mesh=mesh)):
+        step = jax.jit(shard_map(kprog, mesh=mesh,
+                                 in_specs=(spec,) * (3 + len(halos)),
+                                 out_specs=(spec,) * (2 + len(halos))))
+        return step.lower(field, field, field, *halos).compile().as_text()
+
+
+def _kernel_vmem(text, name):
+    """The scoped VMEM the compiler gave the named kernel's custom call."""
+    line = next(l for l in text.splitlines()
+                if re.search(rf"%{name}(\.\d+)? = .*custom-call", l))
+    cfg = re.search(r'"used_scoped_memory_configs":(\[[^\]]*\])', line)
+    return max(int(c["size"]) for c in json.loads(cfg[1]))
+
+
+def _pad_sizes(text):
+    return [int(np.prod([int(d) for d in m.split(",")]))
+            for m in re.findall(r"= f32\[([\d,]+)\]\S* pad\(", text)]
+
+
+def test_minimod_one_chip_program(topo):
+    """Minimod's one-chip cell (256 × 1024² f32): the step is the
+    streamed kernel, with no pass that pads the whole field, inside the
+    kernel's VMEM limit."""
+    text = _minimod_program(topo.devices[:1])
+    assert "tpu_custom_call" in text and "%stencil_step" in text
+    assert not [n for n in _pad_sizes(text) if n >= 256 * 1024 * 1024]
+    assert _kernel_vmem(text, "stencil_step") <= VMEM_LIMIT_BYTES
+
+
 # -- four chips: the fused one-sided kernels ----------------------------------
 
 def test_fused_ring_matmul_stablelm_mlp(ring4):
@@ -147,6 +215,17 @@ def test_fused_stencil_step(ring4):
 
     g = _struct((256, 128, 128), F32, spec)
     _compile(f, g, g)
+
+
+def test_minimod_four_chip_program(topo):
+    """Minimod's whole 1024³ over four chips, carried halos: the interior
+    planes are the streamed kernel's, and the halo puts are still the
+    collective-permute pairs the exchange issues."""
+    text = _minimod_program(topo.devices[:4])
+    assert "tpu_custom_call" in text and "%stencil_interior" in text
+    assert "collective-permute-start" in text
+    assert "collective-permute-done" in text
+    assert _kernel_vmem(text, "stencil_interior") <= VMEM_LIMIT_BYTES
 
 
 def test_fused_moe_dispatch(ring4):
