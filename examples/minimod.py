@@ -50,8 +50,10 @@ def main():
           f"(+{r.compile_s * 1e3:.0f} ms compile)")
     print(f"  decomposition: z_extents={r.z_extents} "
           f"(PGAS region bytes/rank: {r.region_sizes})")
-    print(f"  halo plan: overlap={r.plan.overlap} slots={r.plan.slots} "
-          f"bz={r.plan.bz} puts/step={r.plan.puts_per_step}")
+    print(f"  halo plan: overlap={r.plan.overlap} "
+          f"schedule={'/'.join(r.plan.schedule())} "
+          f"puts/step={r.plan.puts_per_step} "
+          f"halo B/step={r.plan.halo_bytes_per_step}")
     print(f"  wire audit: {r.puts} put call sites, {r.put_bytes} B on the "
           f"OMPCCL log; tracker windows {r.tracker_put_bytes} B, "
           f"{r.fences} fences")

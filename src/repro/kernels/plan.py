@@ -462,30 +462,29 @@ class AttentionRingPlan:
 
 @dataclasses.dataclass(frozen=True)
 class HaloPlan:
-    """Concrete slab/slot plan for one fused halo-overlapped stencil step.
+    """The halo plan of one fused halo-overlapped stencil step.
 
-    The schedule the fused Minimod step executes (TPU kernel and interpret
-    emulation alike — see :mod:`repro.kernels.stencil.fused`):
+    The schedule the fused Minimod step executes (see
+    :mod:`repro.kernels.stencil.fused`):
 
     * **carried halos** (the multi-step time loop): the R-thick *boundary*
       output slabs are computed FIRST (they only need the halos that landed
       last step), their values are immediately put one-sided to the
       neighbors (they are the neighbors' next-step halos), and the
       *interior* — which needs no halo at all — computes under the
-      in-flight exchange.  One neighbor barrier/fence per step.
+      in-flight exchange.  One fence per step.
     * **single step** (no carried halos): the current field's boundary
       slabs are put first, the interior computes under the exchange, and
       the boundary region computes after the fence.
 
-    ``overlap=False`` is the planner's *fallback* plan (degenerate grids
-    with no interior, or a VMEM budget too small to double-buffer the
-    pipeline): exchange-then-compute, still numerically identical.
+    ``overlap`` holds where some axis exchanges and every exchanging axis
+    has an interior to hide the exchange under; ``overlap=False`` is the
+    fallback plan (a lone rank, or a local extent of at most 2·halo on an
+    exchanging axis): exchange-then-compute, still numerically identical.
 
     Extents are LOCAL (the per-rank maximum when extents are asymmetric).
     ``slab_bytes``/``strip_bytes`` are the wire sizes of one Z-slab /
-    Y-strip halo put; ``bz`` is the interior Z-slab height of the DMA
-    pipeline and ``slots`` the number of staging buffers granted by
-    ``StreamPool.plan_slots`` against the VMEM budget.
+    Y-strip halo put.
     """
 
     nz: int
@@ -494,12 +493,8 @@ class HaloPlan:
     z_loc: int = 0
     y_loc: int = 0
     x: int = 0
-    slots: int = 2
-    bz: int = 8
-    by: int = 0               # Y staging chunk (== y_loc when untiled)
     slab_bytes: int = 0
     strip_bytes: int = 0
-    vmem_bytes: int = 0
     overlap: bool = True
 
     def __post_init__(self):
@@ -537,7 +532,7 @@ class HaloPlan:
             (2 * self.strip_bytes if self.ny > 1 else 0)
 
     def schedule(self, *, carried: bool = True) -> Tuple[str, ...]:
-        """Ordered phase names both executions follow.
+        """Ordered phase names the fused step follows.
 
         ``carried=True`` is the time-loop order (halos of the current field
         already landed; the step exchanges the freshly computed boundary),
@@ -906,67 +901,26 @@ class OverlapPlanner:
                 return b, by
         return None
 
-    def plan_stencil_bz(self, z: int, y: int, x: int, dtype,
-                        *, radius: int = 4, bz: int = 8,
-                        budget: Optional[int] = None) -> int:
-        """Z depth of :meth:`plan_stencil_tile`'s tile; 1 where no tile
-        fits (a Z slab never plans below one plane)."""
-        tile = self.plan_stencil_tile(z, y, x, dtype, radius=radius, bz=bz,
-                                      budget=budget)
-        return tile[0] if tile else 1
-
     # -- halo exchange (Minimod) ----------------------------------------------
     def plan_halo_slots(self, z_loc: int, y_loc: int, x: int, dtype,
                         nz: int, *, ny: int = 1, halo: int = 4) -> HaloPlan:
-        """Slab/slot plan for the fused halo-overlapped stencil step.
-
-        The halo landing windows live in HBM (one-sided puts target the
-        PGAS segment); what VMEM must hold is the *staging* pipeline — the
-        (bz + 2·halo)-high halo-extended slabs the boundary and interior
-        passes stream through, ``slots`` of them in flight at once.  The
-        slot count is ``StreamPool.plan_slots``' grant for that working
-        set (the §3.2 bounded-concurrency contract), re-clamped so the
-        pinned bytes actually fit the budget.
+        """Halo plan of the fused halo-overlapped stencil step: the wire
+        size of each put and whether the step overlaps.
 
         Falls back to an ``overlap=False`` plan (exchange-then-compute)
-        rather than emitting an invalid slab plan when the local grid has
-        no interior (extent ≤ 2·halo on an exchanging axis) or the budget
-        cannot double-buffer even the minimum slab.
+        rather than emitting an invalid split when no axis exchanges or
+        the local grid has no interior (extent ≤ 2·halo on an exchanging
+        axis).  The plan's own ``interior_*`` properties are THE
+        definition the step splits by.
         """
         item = _itemsize(dtype)
-        slab = halo * y_loc * x * item if nz > 1 else 0
-        strip = z_loc * halo * x * item if ny > 1 else 0
-        bz = self.plan_stencil_bz(z_loc, y_loc, x, dtype, radius=halo)
-
-        def stage_bytes(by):
-            return (bz + 2 * halo) * (by + 2 * halo) * (x + 2 * halo) * item
-
-        # the staging unit tiles Y once bz has bottomed out (wide grids:
-        # one full Y×X plane can exceed the whole budget by itself)
-        by = y_loc
-        while 2 * stage_bytes(by) > self.vmem_budget and by > 2 * halo:
-            by = max(by // 2, 2 * halo)
-        stage = stage_bytes(by)
-        slots = self.pool.plan_slots(stage, self.vmem_budget)
-        slots = max(2, min(slots, max(self.vmem_budget // max(stage, 1), 2)))
-
         plan = HaloPlan(
             nz=nz, ny=ny, halo=halo, z_loc=z_loc, y_loc=y_loc, x=x,
-            slots=slots, bz=bz, by=by, slab_bytes=slab, strip_bytes=strip,
-            vmem_bytes=slots * stage, overlap=True)
-        # fallback: no interior to hide the exchange under (the plan's own
-        # interior_* properties are THE definition the kernels split by),
-        # or a budget that cannot double-buffer the staging pipeline
-        has_interior = plan.interior_z > 0 and plan.interior_y > 0
-        overlap = bool(plan.exchange_axes) and has_interior and \
-            2 * stage <= self.vmem_budget
-        if not overlap:
-            # fallback plans pipeline nothing: one staging buffer, and the
-            # reported pinned bytes are that single chunk — never a
-            # multi-slot plan the budget cannot hold
-            plan = dataclasses.replace(plan, overlap=False, slots=1,
-                                       vmem_bytes=stage)
-        return plan
+            slab_bytes=halo * y_loc * x * item if nz > 1 else 0,
+            strip_bytes=z_loc * halo * x * item if ny > 1 else 0)
+        overlap = bool(plan.exchange_axes) and plan.interior_z > 0 and \
+            plan.interior_y > 0
+        return dataclasses.replace(plan, overlap=overlap)
 
 
 def _y_tiles(y: int):

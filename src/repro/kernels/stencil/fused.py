@@ -1,28 +1,23 @@
 """Fused halo-overlapped Minimod wave step (paper §4.5, Listings 1–2).
 
 The host-loop Minimod (``benchmarks/bench_minimod.py`` seed shape) exchanged
-halos OUTSIDE the kernel: every step was exchange → fence → full-grid
-stencil, with compute and communication strictly serialized.  This module is
-the same move PR 2 made for the ring matmul, applied to the paper's flagship
-application: the halo exchange becomes in-kernel one-sided puts, and the
-step is split so the interior — which needs no halo at all — computes under
-the in-flight exchange.
+halos OUTSIDE the step: every step was exchange → fence → full-grid
+stencil, with compute and communication strictly serialized.  This module
+makes the ring matmul's move for the paper's flagship application: the
+halo exchange becomes one-sided puts inside the step, and
+the step is split so the interior — which needs no halo at all — computes
+under the in-flight exchange.
 
-One schedule (:meth:`repro.kernels.plan.HaloPlan.schedule`), two executions:
-
-* ``fused_wave_step_tpu`` — ONE ``pallas_call`` runs the whole step: the
-  boundary slabs are deposited into the neighbors' VMEM landing windows via
-  ``pltpu.make_async_remote_copy`` (the ``ompx_put`` of the paper, below the
-  runtime), the interior 25-point stencil runs while the DMAs are in flight,
-  and a per-step neighbor barrier bounds skew to one step.
-* ``fused_wave_step_interpret`` — the CPU-CI emulation: the IDENTICAL phase
-  order with each remote copy realized as an ``ompx_put`` (a
-  ``collective-permute`` remote DMA) started before the interior compute.
-  Differentiable, runs under ``shard_map`` on any backend, and additionally
-  supports what the compiled kernel does not: 2-D (Z×Y) decomposition,
-  **asymmetric** per-rank Z extents (heterogeneous ranks own proportional
-  subdomains — the paper's asymmetric-allocation scenario), and carried
-  halos for the multi-step time loop.
+One schedule (:meth:`repro.kernels.plan.HaloPlan.schedule`), run by XLA:
+each remote copy is an ``ompx_put`` (a ``collective-permute`` remote DMA)
+started before the interior compute.  Where a tile of the streamed Pallas
+stencil (:func:`repro.kernels.stencil.kernel.wave_step_pallas`) fits, that
+kernel computes the interior planes of a Z-only split, or the whole field
+of a lone rank; everything else in the step stays XLA.  The XLA path is
+differentiable, runs under ``shard_map`` on any backend, and supports 2-D
+(Z×Y) decomposition, **asymmetric** per-rank Z extents (heterogeneous
+ranks own proportional subdomains — the paper's asymmetric-allocation
+scenario), and carried halos for the multi-step time loop.
 
 Carried-halo time loop (``return_halos=True``): the halos of the *current*
 field landed during the previous step, so each step computes the R-thick
@@ -40,23 +35,20 @@ the traced valid edge and invalid rows are kept at zero.
 
 from __future__ import annotations
 
-import functools
 from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.backends import payload_bytes
 from repro.core.groups import DiompGroup
 from repro.core.rma import RMAError, halo_window_names, ompx_fence, ompx_put
 from repro.core.spans import recorder
-from repro.core.vma import out_struct, zeros_varying
-from repro.kernels.plan import (LANES, SUBLANES, VMEM_LIMIT_BYTES, HaloPlan,
+from repro.core.vma import zeros_varying
+from repro.kernels.plan import (LANES, VMEM_LIMIT_BYTES, HaloPlan,
                                 default_planner, resolve_interpret)
-from .kernel import leap_plane, wave_step_pallas
+from .kernel import wave_step_pallas
 from .ref import COEFFS, RADIUS
 
 __all__ = [
@@ -65,9 +57,6 @@ __all__ = [
     "Halos",
     "exchange_halos",
     "fused_wave_step",
-    "fused_resident_bytes",
-    "fused_wave_step_interpret",
-    "fused_wave_step_tpu",
 ]
 
 
@@ -184,8 +173,7 @@ def _halo_puts(slabs, zgroup: DiompGroup, ygroup: Optional[DiompGroup],
     """Issue the one-sided puts of a step; returns the (un-fenced) halos.
 
     Every put is a full-ring permute with the wrap-around edge masked to
-    zeros after landing — non-periodic boundaries, same receiver-side
-    guard the compiled kernel applies to its landing windows.
+    zeros after landing: non-periodic boundaries.
     """
     z_lo = z_hi = y_lo = y_hi = None
     if nz > 1:
@@ -247,7 +235,7 @@ def exchange_halos(u, zgroup: DiompGroup, ygroup: Optional[DiompGroup] = None,
 
 
 # ---------------------------------------------------------------------------
-# the interpret / CPU emulation: identical schedule over ompx_put
+# the schedule's phases over ompx_put
 # ---------------------------------------------------------------------------
 
 
@@ -310,22 +298,19 @@ def _combine(interior, boundary, like, *, zv, nz: int, ny: int):
     return _mask_valid(out, zv, Z)
 
 
-def fused_wave_step_interpret(
+def _run_schedule(
     u, u_prev, c2dt2, zgroup: DiompGroup,
-    ygroup: Optional[DiompGroup] = None, *,
-    plan: HaloPlan, dx: float = 1.0, halos: Optional[Halos] = None,
-    z_extents: Optional[Tuple[int, ...]] = None, return_halos: bool = False,
-    tile: Optional[Tuple[int, int]] = None,
+    ygroup: Optional[DiompGroup], *,
+    plan: HaloPlan, dx: float, halos: Optional[Halos],
+    z_extents: Optional[Tuple[int, ...]], return_halos: bool,
+    tile: Optional[Tuple[int, int]],
 ):
     """Execute :meth:`HaloPlan.schedule` with ``ompx_put`` as the remote copy.
 
-    Differentiable and asymmetric/2-D-capable; this is what the application
-    driver trains and serves through on CPU, and what XLA still compiles
-    (and overlaps) on TPU for the configurations the compiled kernel does
-    not cover.  With ``return_halos=True`` the step returns
-    ``(u_next, halos_of_u_next)`` for the carried time loop.  A ``tile``
-    (TPU, Z-only split) hands the interior phase to the streamed Pallas
-    stencil; the rest of the step is unchanged.
+    With ``return_halos=True`` the step returns ``(u_next,
+    halos_of_u_next)`` for the carried time loop.  A ``tile`` (Z-only
+    split) hands the interior phase to the streamed Pallas stencil; the
+    rest of the step is unchanged.
     """
     R = plan.halo
     Z, Y, X = u.shape
@@ -385,146 +370,6 @@ def fused_wave_step_interpret(
 
 
 # ---------------------------------------------------------------------------
-# the TPU kernel: one pallas_call for the whole step
-# ---------------------------------------------------------------------------
-
-
-# the halo-extended field's core starts on a tile boundary (one sublane
-# tile above it, one lane tile left of it), so it is stored aligned and only
-# the star's shifted reads are unaligned
-_OY, _OX = SUBLANES, LANES
-
-
-def _ext_shape(Z: int, Y: int, X: int, R: int) -> Tuple[int, int, int]:
-    """VMEM shape of the fused kernel's halo-extended field."""
-    return (Z + 2 * R, _OY + -(-(Y + R) // SUBLANES) * SUBLANES,
-            _OX + -(-(X + R) // LANES) * LANES)
-
-
-def _fused_stencil_kernel(u_ref, uprev_ref, c2_ref, o_ref, ext, halo_bufs,
-                          send_sems, recv_sems, *, axis: str, plan: HaloPlan,
-                          dx: float):
-    """Kernel body; the phase order is baked statically, ranks are traced.
-
-    ``ext``: VMEM halo-extended field (Dirichlet zeros around the shard,
-    the neighbors' slabs in its first/last R planes once they land).
-    ``halo_bufs``: VMEM (2, R, Y, X) landing windows — slot 0 receives the
-    down-neighbor's hi slab (my lo halo), slot 1 the up-neighbor's lo slab.
-    Like the emulation, the puts run the full ring and the wrap-around edge
-    is masked to zeros after landing (non-periodic boundaries).
-    """
-    R = plan.halo
-    nz = plan.nz
-    Z, Y, X = u_ref.shape
-    dtype = o_ref.dtype
-
-    def planes(lo, hi):
-        def body(z, carry):
-            o_ref[z] = leap_plane(ext, z + R, uprev_ref[z], c2_ref[z],
-                                  oy=_OY, ox=_OX, Y=Y, X=X,
-                                  dx=dx).astype(dtype)
-            return carry
-        lax.fori_loop(lo, hi, body, 0)
-
-    ext[...] = jnp.zeros(ext.shape, ext.dtype)
-    ext[pl.ds(R, Z), pl.ds(_OY, Y), pl.ds(_OX, X)] = u_ref[...]
-
-    if nz == 1:       # whole axis local: pure Dirichlet, no comm at all
-        planes(0, Z)
-        return
-
-    me = lax.axis_index(axis)
-    up = lax.rem(me + 1, nz)
-    down = lax.rem(me + nz - 1, nz)
-
-    # startup barrier: both neighbors entered the kernel before any RDMA
-    # touches their landing windows
-    barrier = pltpu.get_barrier_semaphore()
-    pltpu.semaphore_signal(barrier, inc=1, device_id={axis: down},
-                           device_id_type=pltpu.DeviceIdType.MESH)
-    pltpu.semaphore_signal(barrier, inc=1, device_id={axis: up},
-                           device_id_type=pltpu.DeviceIdType.MESH)
-    pltpu.semaphore_wait(barrier, 2)
-
-    # phase "put": one-sided deposits of my boundary slabs — my hi slab is
-    # the up-neighbor's lo halo, my lo slab the down-neighbor's hi halo
-    rdma_hi = pltpu.make_async_remote_copy(
-        src_ref=u_ref.at[pl.ds(Z - R, R)], dst_ref=halo_bufs.at[0],
-        send_sem=send_sems.at[0], recv_sem=recv_sems.at[0],
-        device_id={axis: up}, device_id_type=pltpu.DeviceIdType.MESH)
-    rdma_lo = pltpu.make_async_remote_copy(
-        src_ref=u_ref.at[pl.ds(0, R)], dst_ref=halo_bufs.at[1],
-        send_sem=send_sems.at[1], recv_sem=recv_sems.at[1],
-        device_id={axis: down}, device_id_type=pltpu.DeviceIdType.MESH)
-    rdma_hi.start()
-    rdma_lo.start()
-
-    # phase "interior": the halo-independent planes compute under the wire
-    if plan.overlap:
-        planes(R, Z - R)
-
-    # phase "fence": the neighbor slabs must have landed
-    rdma_hi.wait()
-    rdma_lo.wait()
-
-    # phase "boundary": edge ranks see Dirichlet zeros, not the wrap-around
-    ext[pl.ds(0, R), pl.ds(_OY, Y), pl.ds(_OX, X)] = jnp.where(
-        me == 0, jnp.zeros_like(halo_bufs[0]), halo_bufs[0])
-    ext[pl.ds(Z + R, R), pl.ds(_OY, Y), pl.ds(_OX, X)] = jnp.where(
-        me == nz - 1, jnp.zeros_like(halo_bufs[1]), halo_bufs[1])
-    if plan.overlap:
-        planes(0, R)
-        planes(Z - R, Z)
-    else:             # degenerate grid: everything is boundary
-        planes(0, Z)
-
-
-def fused_resident_bytes(Z: int, Y: int, X: int, dtype, halo: int) -> int:
-    """VMEM the compiled fused step holds at once: u, u_prev, velocity and
-    output shards, the halo landing windows and the extended field."""
-    item = jnp.dtype(dtype).itemsize
-    ez, ey, ex = _ext_shape(Z, Y, X, halo)
-    return (4 * Z * Y * X + 2 * halo * Y * X + ez * ey * ex) * item
-
-
-def fused_wave_step_tpu(u, u_prev, c2dt2, *, axis: str, plan: HaloPlan,
-                        dx: float = 1.0):
-    """The compiled fused step (requires a real TPU backend).
-
-    Restrictions recorded here rather than hidden: 1-D Z decomposition with
-    symmetric extents (2-D, asymmetric and carried-halo configurations
-    route through the emulation, which XLA compiles and overlaps on TPU);
-    the ring must be a single mesh axis; the whole shard is staged resident
-    in VMEM (the dispatcher routes shards whose
-    :func:`fused_resident_bytes` exceed ``VMEM_LIMIT_BYTES`` to the
-    emulation — the HaloPlan's bz/by staging pipeline describes the
-    emulation's XLA fusion window, not this kernel's residency).
-    """
-    Z, Y, X = u.shape
-    R = plan.halo
-    c2 = jnp.broadcast_to(jnp.asarray(c2dt2, u.dtype), u.shape)
-    return pl.pallas_call(
-        functools.partial(_fused_stencil_kernel, axis=axis, plan=plan, dx=dx),
-        name="stencil_fused_step",
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
-            pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
-        ],
-        out_specs=pl.BlockSpec(memory_space=pltpu.MemorySpace.VMEM),
-        out_shape=out_struct((Z, Y, X), u.dtype, u, u_prev, c2),
-        scratch_shapes=[
-            pltpu.VMEM(_ext_shape(Z, Y, X, R), u.dtype),
-            pltpu.VMEM((2, R, Y, X), u.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            collective_id=1, vmem_limit_bytes=VMEM_LIMIT_BYTES),
-    )(u, u_prev, c2)
-
-
-# ---------------------------------------------------------------------------
 # dispatch
 # ---------------------------------------------------------------------------
 
@@ -548,18 +393,16 @@ def fused_wave_step(
     enables asymmetric Z decomposition; ``halos``/``return_halos`` thread
     the carried-halo state of the multi-step time loop.
 
-    Off the interpreter, a single rank (no exchanging axis) runs the
-    streamed Pallas stencil (:func:`~repro.kernels.stencil.kernel.
-    wave_step_pallas`) wherever the planner finds it a tile
-    (:meth:`~repro.kernels.plan.OverlapPlanner.plan_stencil_tile`);
-    configurations the compiled fused kernel does not cover (2-D,
-    asymmetric, carried halos, shards over ``VMEM_LIMIT_BYTES``) route
-    through the emulation — which XLA still compiles on TPU — with its
-    interior in the streamed kernel for a Z-only split.  Each trace counts
-    the path it took in :func:`repro.core.spans.recorder` (``stencil.
-    dispatch.pallas`` where a Pallas kernel computes the star, ``stencil.
-    dispatch.emulation`` where XLA's fusion of it does).  No Pallas path
-    has a VJP: differentiate through ``interpret=True``.
+    Off the interpreter, the streamed Pallas stencil (:func:`~repro.
+    kernels.stencil.kernel.wave_step_pallas`) takes the star wherever X
+    is on the lane tile and the planner finds it a tile (:meth:`~repro.
+    kernels.plan.OverlapPlanner.plan_stencil_tile`): the whole field of a
+    lone rank, or the interior planes of a symmetric Z-only split whose
+    plan overlaps.  Every other case runs the schedule in XLA alone.  Each
+    trace counts the path it took in :func:`repro.core.spans.recorder`
+    (``stencil.dispatch.pallas`` where the Pallas kernel computes the
+    star, ``stencil.dispatch.emulation`` where XLA's fusion of it does).
+    No Pallas path has a VJP: differentiate through ``interpret=True``.
     """
     from repro.core.compat import axis_size
 
@@ -593,36 +436,19 @@ def fused_wave_step(
     if plan.halo != RADIUS:
         raise ValueError(f"plan.halo={plan.halo} != stencil radius {RADIUS}")
 
-    interp = resolve_interpret(interpret)
-    # the streamed kernel's tile, for a Z-only split or a lone rank: the
-    # whole field, or the halo-independent interior planes [R, Z - R);
-    # the kernel keeps X whole on the lanes
+    # the streamed kernel's tile: the whole field of a lone rank, or the
+    # interior planes [R, Z - R) of a split; the kernel keeps X whole on
+    # the lanes
     tile = None
-    if not interp and ny == 1 and z_extents is None and X % LANES == 0:
+    if (not resolve_interpret(interpret) and ny == 1 and z_extents is None
+            and X % LANES == 0 and (nz == 1 or plan.overlap)):
         tile = default_planner().plan_stencil_tile(
             Z if nz == 1 else Z - 2 * RADIUS, Y, X, u.dtype, radius=RADIUS,
             budget=VMEM_LIMIT_BYTES)
-    if tile is not None and not plan.exchange_axes:
-        # one rank holds the whole grid: nothing to exchange
-        recorder().count(DISPATCH_PALLAS)
+    recorder().count(DISPATCH_EMULATION if tile is None else DISPATCH_PALLAS)
+    if tile is not None and nz == 1:
         out = wave_step_pallas(u, u_prev, c2dt2, dx=dx, tile=tile)
         return (out, None) if return_halos else out
-    # the compiled fused kernel keeps u/u_prev/c2/out + the halo landing
-    # windows wholly resident in VMEM; carried halos and larger shards take
-    # the emulation, which XLA pipelines through HBM on TPU, its interior
-    # in the streamed kernel where a tile fits
-    if interp or (ny > 1 or z_extents is not None or halos is not None
-                  or return_halos
-                  or fused_resident_bytes(Z, Y, X, u.dtype, RADIUS)
-                  > VMEM_LIMIT_BYTES):
-        if not plan.overlap:
-            tile = None
-        recorder().count(DISPATCH_EMULATION if tile is None
-                         else DISPATCH_PALLAS)
-        return fused_wave_step_interpret(
-            u, u_prev, c2dt2, zgroup, ygroup, plan=plan, dx=dx,
-            halos=halos, z_extents=z_extents, return_halos=return_halos,
-            tile=tile)
-    recorder().count(DISPATCH_PALLAS)
-    return fused_wave_step_tpu(u, u_prev, c2dt2, axis=zgroup.axes[0],
-                               plan=plan, dx=dx)
+    return _run_schedule(
+        u, u_prev, c2dt2, zgroup, ygroup, plan=plan, dx=dx, halos=halos,
+        z_extents=z_extents, return_halos=return_halos, tile=tile)

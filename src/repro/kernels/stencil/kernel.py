@@ -40,29 +40,7 @@ from repro.kernels.plan import (LANES, SUBLANES, VMEM_LIMIT_BYTES,
                                 stencil_halo_rows)
 from .ref import COEFFS, RADIUS
 
-__all__ = ["leap_plane", "wave_step_pallas"]
-
-
-def leap_plane(ext, zc, prev, c2, *, oy: int, ox: int, Y: int, X: int,
-               dx: float):
-    """One output plane of the leapfrog step, read from a halo-extended
-    VMEM ref ``ext``: the plane's center sits at ``ext[zc, oy:oy+Y,
-    ox:ox+X]`` with at least R planes / rows / lanes of halo around it.
-    ``prev``/``c2`` are that plane's (Y, X) u_prev and velocity values.
-    The term order mirrors :func:`repro.kernels.stencil.ref.laplacian_ref`
-    (radius, then axis)."""
-    def view(dz, dy, dx_):
-        return ext[zc + dz, pl.ds(oy + dy, Y), pl.ds(ox + dx_, X)]
-
-    c0, *cs = COEFFS
-    center = view(0, 0, 0)
-    lap = 3.0 * c0 * center
-    for r, c in zip(range(1, RADIUS + 1), cs):
-        lap = lap + c * (view(-r, 0, 0) + view(r, 0, 0))
-        lap = lap + c * (view(0, -r, 0) + view(0, r, 0))
-        lap = lap + c * (view(0, 0, -r) + view(0, 0, r))
-    lap = lap / (dx * dx)
-    return 2.0 * center - prev + c2 * lap
+__all__ = ["wave_step_pallas"]
 
 
 def _tile_copies(Y: int, by: int, hy: int):

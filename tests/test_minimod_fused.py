@@ -91,20 +91,22 @@ def test_fused_step_matches_reference(Z, Y, X, nz, ny, ext):
     np.testing.assert_allclose(got, want, atol=3e-6)
 
 
-@pytest.mark.parametrize("Z,Y,X,nz", [
-    (64, 12, 10, 4),     # the fused kernel: puts under the interior planes
-    (32, 12, 10, 4),     # the fused kernel, no interior: all boundary
-    (16, 8, 8, 1),       # one rank, X off the lane tile: the resident kernel
-    (16, 16, 128, 1),    # one rank: the streamed stencil kernel
+@pytest.mark.parametrize("Z,Y,X,nz,pallas", [
+    (64, 16, 128, 4, 1),   # single-step split: puts under the kernel's interior
+    (32, 16, 128, 4, 0),   # no interior: the fallback schedule, all XLA
+    (16, 8, 8, 1, 0),      # one rank, X off the lane tile: the XLA step
+    (16, 16, 128, 1, 1),   # one rank: the streamed stencil kernel
 ])
-def test_tpu_kernels_match_reference_in_tpu_interpreter(Z, Y, X, nz):
-    """The compiled path's kernel bodies (remote copies and semaphores
-    simulated across the CPU devices by Pallas' TPU interpreter, whose own
-    ops carry no vma types)."""
+def test_tpu_kernels_match_reference_in_tpu_interpreter(Z, Y, X, nz, pallas):
+    """The path the chip takes off the interpreter, the streamed kernel's
+    body simulated by Pallas' TPU interpreter (whose own ops carry no vma
+    types)."""
     from jax.experimental.pallas import tpu as pltpu
 
+    n0 = _dispatch_counts()
     with pltpu.force_tpu_interpret_mode():
         got, want = _run_step(Z, Y, X, nz, interpret=False, check_vma=False)
+    assert list(_dispatch_counts() - n0) == [pallas, 1 - pallas]
     np.testing.assert_allclose(got, want, atol=3e-6)
 
 
@@ -207,25 +209,6 @@ def test_fused_gradients_flow():
 # planner: degenerate cases fall back, never an invalid slab plan
 # ---------------------------------------------------------------------------
 
-def test_plan_halo_slots_consumes_plan_slots():
-    calls = []
-
-    class SpyPool(StreamPool):
-        def plan_slots(self, working_set_bytes, vmem_budget=64 * 2**20):
-            calls.append(working_set_bytes)
-            return super().plan_slots(working_set_bytes, vmem_budget)
-
-    planner = OverlapPlanner(pool=SpyPool(max_active=4))
-    plan = planner.plan_halo_slots(32, 16, 16, jnp.float32, 4)
-    assert calls, "plan_slots was never queried"
-    assert plan.overlap and 2 <= plan.slots <= 4
-    assert plan.slab_bytes == RADIUS * 16 * 16 * 4
-    assert plan.schedule(carried=True) == ("boundary", "put", "interior",
-                                           "fence")
-    assert plan.schedule(carried=False) == ("put", "interior", "fence",
-                                            "boundary")
-
-
 def test_plan_halo_slots_degenerate_grid_falls_back():
     planner = default_planner()
     # local extent == 2*R: no interior -> fallback schedule
@@ -241,36 +224,46 @@ def test_plan_halo_slots_degenerate_grid_falls_back():
     assert not flat.overlap
 
 
-def test_plan_halo_slots_tiny_vmem_falls_back():
-    planner = OverlapPlanner(pool=StreamPool(max_active=8), vmem_budget=1024)
-    plan = planner.plan_halo_slots(64, 64, 64, jnp.float32, 4)
-    assert plan.bz == 1                      # slab pipeline bottomed out
-    assert not plan.overlap                  # cannot double-buffer: fallback
-    assert plan.schedule() == ("put", "fence", "all")
+_CARRIED = ("boundary", "put", "interior", "fence")
+_SINGLE = ("put", "interior", "fence", "boundary")
+_FALLBACK = ("put", "fence", "all")
 
 
-def test_plan_halo_slots_wide_grid_tiles_y():
-    """Paper-scale planes exceed VMEM whole; the staging chunk tiles Y so
-    the overlap schedule survives instead of falling back."""
-    plan = default_planner().plan_halo_slots(128, 1024, 1024, jnp.float32, 8)
-    assert plan.overlap
-    assert plan.by < plan.y_loc
-    # the PINNED pipeline (all slots) must fit the budget, not just one slab
-    assert plan.vmem_bytes <= default_planner().vmem_budget
+@pytest.mark.parametrize("z,y,x,nz,ny,budget,schedules", [
+    (32, 16, 16, 4, 1, None, (_CARRIED, _SINGLE)),      # overlapped 1-D
+    (2 * RADIUS, 16, 16, 4, 1, None, (_FALLBACK,) * 2),  # no Z interior
+    (32, 16, 16, 1, 1, None, (("all",),) * 2),          # lone rank
+    (32, 2 * RADIUS, 16, 2, 2, None, (_FALLBACK,) * 2),  # 2-D, flat Y
+    (32, 32, 16, 2, 2, None, (_CARRIED, _SINGLE)),      # 2-D with an interior
+    (128, 1024, 1024, 8, 1, None, (_CARRIED, _SINGLE)),  # paper scale
+    (64, 64, 64, 4, 1, 1024, (_CARRIED, _SINGLE)),      # budget plays no part
+])
+def test_plan_halo_slots_overlap_rule(z, y, x, nz, ny, budget, schedules):
+    """``overlap``: some axis exchanges and every exchanging axis has an
+    interior; the VMEM budget does not enter.  The put sizes are one
+    halo-thick slab (Z) or strip (Y) of the local shard."""
+    planner = default_planner() if budget is None else OverlapPlanner(
+        pool=StreamPool(max_active=8), vmem_budget=budget)
+    plan = planner.plan_halo_slots(z, y, x, jnp.float32, nz, ny=ny)
+    assert plan.overlap == (schedules[0] == _CARRIED)
+    assert (plan.schedule(carried=True),
+            plan.schedule(carried=False)) == schedules
+    assert plan.slab_bytes == (RADIUS * y * x * 4 if nz > 1 else 0)
+    assert plan.strip_bytes == (z * RADIUS * x * 4 if ny > 1 else 0)
+    assert plan.puts_per_step == 2 * ((nz > 1) + (ny > 1))
+    assert plan.halo_bytes_per_step == 2 * (plan.slab_bytes
+                                            + plan.strip_bytes)
 
 
-def test_plan_stencil_bz_degenerate():
+def test_plan_stencil_tile_degenerate():
     planner = default_planner()
     # bz exceeding the Z extent clamps to it
-    assert planner.plan_stencil_bz(3, 8, 8, jnp.float32, bz=64) == 3
     assert planner.plan_stencil_tile(3, 8, 8, jnp.float32, bz=64) == (3, 8)
     # grid smaller than the stencil support still yields a positive slab
-    assert planner.plan_stencil_bz(2, 2, 2, jnp.float32) >= 1
     assert planner.plan_stencil_tile(2, 2, 2, jnp.float32) == (2, 2)
-    # budget too small for any slab bottoms out at one plane
+    # a budget too small for one plane's tile has no tile at all: the
+    # dispatcher takes the XLA path
     tiny = OverlapPlanner(pool=StreamPool(max_active=8), vmem_budget=256)
-    assert tiny.plan_stencil_bz(64, 64, 64, jnp.float32) == 1
-    # ... and has no tile at all: the dispatcher takes the XLA path
     assert tiny.plan_stencil_tile(64, 64, 64, jnp.float32) is None
     # not even 8 rows of a very wide plane fit the kernel's limit
     assert planner.plan_stencil_tile(8, 64, 131072, jnp.float32,

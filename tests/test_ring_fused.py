@@ -117,7 +117,8 @@ def test_planner_attention_and_stencil_plans():
     assert planner.plan_attention_block(1, 48, 64, 64, jnp.float32) == 48
     assert planner.plan_attention_block(512, 8192, 128, 128,
                                         jnp.bfloat16) >= 128
-    assert 1 <= planner.plan_stencil_bz(24, 20, 28, jnp.float32) <= 8
+    bz, by = planner.plan_stencil_tile(24, 20, 28, jnp.float32)
+    assert 1 <= bz <= 8 and by == 20
 
 
 def test_resolvers():

@@ -42,7 +42,6 @@ from repro.kernels.ring_matmul.fused import (fused_ring_allgather_matmul_tpu,
                                              fused_ring_resident_bytes)
 from repro.kernels.ring_matmul.kernel import matmul_pallas
 from repro.kernels.stencil import fused as stencil_fused
-from repro.kernels.stencil.fused import fused_wave_step_tpu
 from repro.kernels.stencil.ops import wave_step
 from repro.models import api as model_api
 from repro.models import schema
@@ -201,20 +200,27 @@ def test_fused_ring_matmul_stablelm_mlp(ring4):
 
 
 def test_fused_stencil_step(ring4):
-    """The fused halo-overlapped step on a shard that stays VMEM-resident
-    (Z 4·64 x 128 x 128 f32)."""
+    """The single-step split (no carried halos) over a Z ring of four,
+    4·64 x 128 x 128 f32: the puts are collective-permutes started before
+    the interior planes, which the streamed kernel computes."""
     plan = OverlapPlanner().plan_halo_slots(64, 128, 128, F32, 4)
+    assert plan.overlap
     mesh = Mesh(np.array(ring4.devices).reshape(4, 1), ("z", "y"))
     spec = NamedSharding(mesh, P("z", "y"))
+    zg = DiompGroup(("z",), name="z")
 
     def f(u, up):
         return jax.shard_map(
-            lambda a, b: fused_wave_step_tpu(a, b, 0.1, axis="z", plan=plan),
+            lambda a, b: stencil_fused.fused_wave_step(
+                a, b, 0.1, zg, plan=plan, interpret=False),
             mesh=mesh, in_specs=(P("z", "y"), P("z", "y")),
             out_specs=P("z", "y"))(u, up)
 
     g = _struct((256, 128, 128), F32, spec)
-    _compile(f, g, g)
+    with use_default(DiompContext(mesh=mesh)):
+        text = _compile(f, g, g).as_text()
+    assert "%stencil_interior" in text
+    assert "collective-permute-start" in text
 
 
 def test_minimod_four_chip_program(topo):
